@@ -25,8 +25,9 @@ import numpy as np
 
 from repro.core import tsmm as T
 from repro.kernels import ref
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(0)
 a = jnp.asarray(rng.standard_normal((4096, 2048)), jnp.float32)
 b = jnp.asarray(rng.standard_normal((2048, 16)), jnp.float32)

@@ -26,21 +26,33 @@ import logging
 from typing import Optional
 
 from repro.core import registry
-from repro.core.hw import TPU_V5E, HwSpec
+from repro.core.hw import TPU_V5E, HwSpec, spec_for_device_kind
 from repro.core.plan import (SKINNY_MAX, BucketGrid, Plan, PlanGrid, PlanSet,
                              Problem, is_tsmm, schedules_for)
 from repro.core.vmem_model import feasible, predict
 
 log = logging.getLogger(__name__)
 
-# The hardware model trace-time planning ranks against.  The serving
-# engine swaps in a calibrated spec (fitted from the measurement cache)
-# so registry misses inside jit traces are ranked by measured reality,
-# not the datasheet — the "measure -> model -> plan" loop closed.
-_DEFAULT_HW: HwSpec = TPU_V5E
+# The hardware model trace-time planning ranks against, resolved on first
+# use (see ``default_hw``).  The serving engine swaps in a calibrated spec
+# (fitted from the measurement cache) so registry misses inside jit
+# traces are ranked by measured reality, not the datasheet — the
+# "measure -> model -> plan" loop closed.
+_DEFAULT_HW: Optional[HwSpec] = None
 
 
 def default_hw() -> HwSpec:
+    """The planning spec.  On a TPU it is the spec of the attached chip's
+    ``device_kind`` (an unknown kind raises).  Every other backend plans
+    against the v5e model: CPU runs check plans and control flow for the
+    chip this repository targets, they have no VMEM of their own."""
+    global _DEFAULT_HW
+    if _DEFAULT_HW is None:
+        import jax
+        if jax.default_backend() == "tpu":
+            _DEFAULT_HW = spec_for_device_kind(jax.devices()[0].device_kind)
+        else:
+            _DEFAULT_HW = TPU_V5E
     return _DEFAULT_HW
 
 

@@ -45,11 +45,14 @@ DROPPED_TERM_EFFICIENCY = 1e9
 
 
 def _materialize(plan: Plan, seed: int = 0):
+    """Deterministic operands for one plan, drawn on the device: an
+    lm-head-sized weight drawn on the host would take seconds per
+    candidate."""
     p = plan.problem
-    rng = np.random.default_rng(seed)
     dt = jnp.dtype(p.dtype) if p.dtype != "bfloat16" else jnp.bfloat16
-    a = jnp.asarray(rng.standard_normal((p.m, p.k), dtype=np.float32)).astype(dt)
-    b = jnp.asarray(rng.standard_normal((p.k, p.n), dtype=np.float32)).astype(dt)
+    ka, kb = jax.random.split(jax.random.PRNGKey(seed))
+    a = jax.random.normal(ka, (p.m, p.k), jnp.float32).astype(dt)
+    b = jax.random.normal(kb, (p.k, p.n), jnp.float32).astype(dt)
     return a, b
 
 

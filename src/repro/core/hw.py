@@ -1,10 +1,10 @@
 """Hardware model for the target platform (TPU v5e-class chip).
 
-The container is CPU-only; these constants drive (a) the autotuner's
-predictive model (the paper's Eq.2/Eq.3 cache bounds become VMEM bounds),
-and (b) the roofline terms in benchmarks/roofline.py.  All figures are the
-ones fixed by the assignment brief: 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI.
+These constants drive (a) the autotuner's predictive model (the paper's
+Eq.2/Eq.3 cache bounds become VMEM bounds), (b) the scoped-VMEM limit
+every Pallas kernel compiles with, and (c) the roofline terms in
+benchmarks/roofline.py.  Peaks are Google Cloud's published TPU v5e
+figures: 197 TFLOP/s bf16, 819 GB/s HBM, 16 GB HBM; ~50 GB/s/link ICI.
 """
 
 from __future__ import annotations
@@ -58,13 +58,30 @@ TPU_V5E = HwSpec(
     ici_bw_per_link=50e9,
     ici_links=4,
     hbm_bytes=16 * 1024 * MiB,
-    # Conservative, configurable working-set budget for Pallas pipelines.
-    vmem_bytes=64 * MiB,
+    vmem_bytes=128 * MiB,         # physical VMEM per TensorCore
 )
 
-# Fraction of VMEM the autotuner may plan into (double buffering etc. is
-# accounted explicitly; this margin covers compiler scratch + semaphores).
-VMEM_USABLE_FRACTION = 0.75
+# The ONE VMEM budget: the feasibility gate (``vmem_model.feasible``)
+# admits a plan only if its modelled working set fits it, and every
+# ``pallas_call`` passes it to Mosaic as ``vmem_limit_bytes`` (without
+# it Mosaic applies a 16 MiB default and refuses admitted plans).  Kept
+# well below the physical VMEM: the model's estimate and the compiler's
+# allocation differ by a few MiB in either direction, and the margin
+# covers compiler scratch and semaphores.
+VMEM_LIMIT_BYTES = 48 * MiB
+
+# ``jax.devices()[i].device_kind`` -> spec.  A TPU whose kind is not here
+# is an error (see ``spec_for_device_kind``), never a silent default.
+SPECS_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def spec_for_device_kind(kind: str) -> HwSpec:
+    try:
+        return SPECS_BY_DEVICE_KIND[kind]
+    except KeyError:
+        raise ValueError(
+            f"no hardware spec for device kind {kind!r}; known: "
+            f"{sorted(SPECS_BY_DEVICE_KIND)} (add it to core/hw.py)") from None
 
 DTYPE_BYTES = {"float32": 4, "bfloat16": 2, "float16": 2, "float64": 8, "int8": 1}
 

@@ -120,17 +120,19 @@ def concrete_mesh(spec: str):
     """``data=4,model=2`` -> a real device Mesh, or None when the host
     has too few devices.  ``--precompile`` needs one: XLA compiles (and
     serializes) sharded executables only against concrete devices."""
+    import math
+
     import jax
-    import numpy as np
-    from jax.sharding import Mesh
+
+    from repro.launch.mesh import make_mesh
     axes = [(name.strip(), int(size))
             for name, size in (p.split("=") for p in spec.split(","))]
-    need = int(np.prod([s for _, s in axes]))
+    need = math.prod(s for _, s in axes)
     devs = jax.devices()
     if len(devs) < need:
         return None
-    return Mesh(np.asarray(devs[:need]).reshape([s for _, s in axes]),
-                tuple(name for name, _ in axes))
+    return make_mesh(tuple(s for _, s in axes),
+                     tuple(name for name, _ in axes), devices=devs[:need])
 
 
 def serving_problems(cfg, buckets: tuple = SERVE_BUCKETS,
@@ -268,6 +270,8 @@ def main(argv=None):
                          "--check validates serving coverage against the "
                          "exported artifact, not just the local cache")
     args = ap.parse_args(argv)
+    from repro.serve.programs import enable_compilation_cache
+    enable_compilation_cache()
     if args.find_db:
         from repro.tuning.find_db import attach
         attach(args.find_db)
@@ -344,9 +348,9 @@ def main(argv=None):
         return
 
     if args.calibrate:
+        from repro.core.autotuner import default_hw
         from repro.core.evaluator import MIN_FIT_RECORDS, calibrated_hw
-        from repro.core.hw import TPU_V5E
-        hw_cal = calibrated_hw(TPU_V5E)
+        hw_cal = calibrated_hw(default_hw())
         n_rec = len(registry.measurements())
         if not hw_cal.calibrated:
             if n_rec < MIN_FIT_RECORDS:

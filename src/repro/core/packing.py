@@ -37,16 +37,22 @@ class PackedTensor:
     re-deriving the registry key — which a sharded engine could not do
     (its plans are keyed by per-shard dims and num_shards).  Empty for
     manually packed tensors.
+
+    ``shard_axes`` is the (row-block, column-block) mesh axis the serving
+    engine shards the block-count dims over ((None, None) off-mesh).  A
+    Mosaic kernel cannot be partitioned by XLA, so ``tsmm_dot`` reads it
+    to run one kernel per shard.
     """
 
     blocks: jnp.ndarray
     orig_rows: int      # pre-padding
     orig_cols: int
     kernel_specs: tuple = ()
+    shard_axes: tuple = (None, None)
 
     def tree_flatten(self):
         return (self.blocks,), (self.orig_rows, self.orig_cols,
-                                self.kernel_specs)
+                                self.kernel_specs, self.shard_axes)
 
     @classmethod
     def tree_unflatten(cls, aux, children):

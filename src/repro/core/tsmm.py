@@ -25,7 +25,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -97,6 +96,49 @@ def _laddered(orientation: str, breaker_key: str, planned, xla_twin, gemm):
         stats.record("kernel.xla", key=breaker_key, fallback="gemm",
                      error=str(e))
         return gemm()
+
+
+def _mosaic_mesh(impl: Optional[str]):
+    """The ambient serving mesh when ``impl`` lowers to Mosaic kernels,
+    else None.  XLA cannot partition a Mosaic kernel, so under a mesh each
+    kernel runs per shard inside ``shard_map``."""
+    from repro.sharding.context import get_ctx
+    ctx = get_ctx()
+    if ctx is None or ops._resolve(impl) == "xla":
+        return None
+    return ctx.mesh
+
+
+def _skinny_per_shard(mesh, b: PackedTensor, run, a2, bias, act):
+    """``run(x, blocks, bias, act)`` on each device's shard of the packed
+    weight ``b`` (its ``shard_axes``).  Column blocks sharded: each device
+    computes its own output columns, bias and activation fused.  Row
+    blocks sharded: each device contracts its k panel and the f32
+    partials are summed across the axis before bias and activation.
+    Returns (m, padded n)."""
+    row_ax, col_ax = b.shard_axes
+    nk, nn, bk, bn = b.blocks.shape[-4:]
+    a2 = ops.pad2(a2, a2.shape[0], nk * bk)
+    args = [a2, b.blocks]
+    specs = [P(None, row_ax), P(row_ax, col_ax, None, None)]
+    if bias is not None and row_ax is None:
+        args.append(ops._pad_bias(bias, nn * bn))
+        specs.append(P(col_ax))
+
+    def local(x, w, *bias_):
+        if row_ax is None:
+            return run(x, w, bias_[0] if bias_ else None, act)
+        part = run(x, w, None, None).astype(jnp.float32)
+        return jax.lax.psum(part, row_ax)
+
+    out = jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
+                        out_specs=P(None, col_ax), check_vma=False)(*args)
+    if row_ax is None:
+        return out
+    if bias is not None:
+        out = out + ops._pad_bias(bias, nn * bn).astype(jnp.float32)
+    from repro.kernels.ref import act_ref
+    return act_ref(out, act).astype(a2.dtype)
 
 
 def impl_choice() -> str:
@@ -206,9 +248,16 @@ def tsmm_dot(a, b, *, bias=None, act: Optional[str] = None,
         sched = sched_override or sched
 
         def _packed(use_impl):
-            return variants.run_skinny_a(
-                spec, a2, b.blocks, bias, act, bk=bk, bn=bn, packed=True,
-                impl=use_impl, schedule=sched)[:, : b.orig_cols]
+            def run(x, w, bias_, act_):
+                return variants.run_skinny_a(
+                    spec, x, w, bias_, act_, bk=bk, bn=bn, packed=True,
+                    impl=use_impl, schedule=sched)
+            mesh = _mosaic_mesh(use_impl)
+            if mesh is not None:
+                out = _skinny_per_shard(mesh, b, run, a2, bias, act)
+            else:
+                out = run(a2, b.blocks, bias, act)
+            return out[:, : b.orig_cols]
 
         out = _laddered(
             "skinny", f"skinny_a/{m}x{k}x{b.orig_cols}/{spec.key()}",
@@ -218,7 +267,9 @@ def tsmm_dot(a, b, *, bias=None, act: Optional[str] = None,
         return out.reshape(*lead, b.orig_cols)
 
     n = b.shape[-1]
-    if plan is None and is_tsmm(m, k, n):
+    if plan is None and is_tsmm(m, k, n) and _mosaic_mesh(impl) is None:
+        # (under a mesh a weight left unpacked serves as a plain GEMM,
+        # which XLA partitions; a Mosaic kernel would need its sharding)
         plan = plan_for_matmul(m, k, n, str(a.dtype))
     if plan is not None and plan.orientation == "skinny_a":
         spec = _override_spec(plan.kernel, override, "skinny_a")
@@ -408,8 +459,9 @@ def distributed_tsmm(a, b, mesh: Mesh, axis: str = "data", *,
         return ops.tsmm(a_blk, b_full, bm=local_plan.bm, bk=local_plan.bk,
                         impl=impl)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P(axis, None), P(None, None)),
-                   out_specs=P(axis, None))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(axis, None), P(None, None)),
+                       out_specs=P(axis, None))
     return fn(a, b)
 
 
@@ -421,20 +473,10 @@ def conventional_ksplit(a, b, mesh: Mesh, axis: str = "data", *,
         part = jnp.dot(a_blk, b_blk, preferred_element_type=jnp.float32)
         return jax.lax.psum(part, axis).astype(a_blk.dtype)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P(None, axis), P(axis, None)),
-                   out_specs=P(None, None))
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(None, axis), P(axis, None)),
+                       out_specs=P(None, None))
     return fn(a, b)
-
-
-def _shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """shard_map whose output replication the VMA type system can't prove
-    (ring accumulation makes outputs replicated only after all steps)."""
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
 
 
 def overlapped_ring_tsmm(a, b, mesh: Mesh, axis: str = "data", *,
@@ -467,6 +509,9 @@ def overlapped_ring_tsmm(a, b, mesh: Mesh, axis: str = "data", *,
                                       length=shards)
         return acc.astype(a_blk.dtype)
 
-    fn = _shard_map_unchecked(local, mesh, (P(None, axis), P(axis, None)),
-                              P(None, None))
+    # the VMA type system cannot prove the output replicated (the ring
+    # accumulation makes it so only after all steps): unchecked
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(None, axis), P(axis, None)),
+                       out_specs=P(None, None), check_vma=False)
     return fn(a, b)

@@ -28,7 +28,8 @@ from __future__ import annotations
 
 import dataclasses
 
-from repro.core.hw import TPU_V5E, VMEM_USABLE_FRACTION, HwSpec, dtype_bytes
+from repro.core.hw import (TPU_V5E, VMEM_LIMIT_BYTES, HwSpec, MiB,
+                           dtype_bytes)
 from repro.core.plan import SEMANTICS, Plan, Problem
 from repro.kernels.variants import grammar
 from repro.kernels.variants.grammar import GenSpec, from_kernel_spec
@@ -44,6 +45,11 @@ def nominal(hw: HwSpec) -> HwSpec:
     roofline the fit regresses against (see :func:`features`)."""
     return dataclasses.replace(hw, mxu_efficiency=1.0, hbm_efficiency=1.0,
                                calibrated=False)
+
+
+# Mosaic's fixed per-kernel scratch beyond the modelled buffers
+# (semaphores, relayout copies): see ``vmem_bytes_needed``.
+COMPILER_RESERVE_BYTES = 2 * MiB
 
 
 def _ceil(a, b):
@@ -113,7 +119,15 @@ def vmem_bytes_needed(plan: Plan, hw: HwSpec = TPU_V5E) -> int:
     no multibuffering on it), ``acc=revisit`` trades the VMEM scratch
     accumulator for an fp32 output block, ``loop=kouter`` additionally
     streams that fp32 block back in as an aliased input, and k-split
-    points stream fp32 partial blocks out."""
+    points stream fp32 partial blocks out.
+
+    On top of the pipeline buffers comes Mosaic's own scratch, sized from
+    deviceless v5e compiles (the smallest ``vmem_limit_bytes`` each plan
+    compiled under): the fp32 result of each step's dot, four such
+    copies when the accumulator is an output block (its load, the sum and
+    the store around the dot), and a fixed reserve for semaphores and
+    relayouts.  Without them the largest admitted plans needed up to
+    ~1.4x the modelled bytes and were refused at compile time."""
     p = plan.problem
     eb = dtype_bytes(p.dtype)
     g = _gen(plan)
@@ -137,6 +151,8 @@ def vmem_bytes_needed(plan: Plan, hw: HwSpec = TPU_V5E) -> int:
             out = 2 * plan.bm * n_pad * 4
         if g.bres == "resident":
             b = _ceil(p.k, plan.bk) * plan.bk * n_pad * eb  # full B, once
+        dot = plan.bm * n_pad * 4
+        out_acc = g.loop == "kouter" or (g.acc == "revisit" and g.ksplit == 1)
     else:  # skinny_a
         sl = hw.sublane.get(p.dtype, 8)
         m_pad = _ceil(p.m, sl) * sl
@@ -151,7 +167,10 @@ def vmem_bytes_needed(plan: Plan, hw: HwSpec = TPU_V5E) -> int:
             out = 2 * m_pad * plan.bn * 4
         if g.bres == "resident":
             a = m_pad * _ceil(p.k, plan.bk) * plan.bk * eb  # full X, once
-    return a + b + acc + out
+        dot = m_pad * plan.bn * 4
+        out_acc = g.acc == "revisit" and g.ksplit == 1
+    scratch = (4 if out_acc else 1) * dot + COMPILER_RESERVE_BYTES
+    return a + b + acc + out + scratch
 
 
 def feasible(plan: Plan, hw: HwSpec = TPU_V5E) -> bool:
@@ -198,7 +217,7 @@ def feasible(plan: Plan, hw: HwSpec = TPU_V5E) -> bool:
             return False
         if len(sched.dims) != grid_rank(plan):
             return False
-    return vmem_bytes_needed(plan, hw) <= hw.vmem_bytes * VMEM_USABLE_FRACTION
+    return vmem_bytes_needed(plan, hw) <= VMEM_LIMIT_BYTES
 
 
 def epilogue_roundtrip_bytes(plan: Plan) -> int:

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tsmm import _compiler_params
+
 NEG_INF = -1e30
 
 
@@ -106,18 +108,8 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int = 256,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
         ],
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d)
-
-
-def _compiler_params():
-    try:
-        return pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))
-    except (AttributeError, TypeError):
-        return pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"))

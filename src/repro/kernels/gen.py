@@ -138,7 +138,7 @@ def _tall_kinner(a, b, bias, *, bm, bk, act, packed, resident, revisit,
         b_spec = (pl.BlockSpec((k, n), lambda i, j: (0, 0)) if resident
                   else pl.BlockSpec((bk, n), lambda i, j: (j, 0)))
         o_spec = pl.BlockSpec((bm, n), lambda i, j: (i, 0))
-        bias_spec = pl.BlockSpec((n,), lambda i, j: (0,))
+        bias_spec = pl.BlockSpec((1, n), lambda i, j: (0, 0))
     else:
         a_spec = (pl.BlockSpec((1, 1, bm, bk),
                                lambda p, i, j: (row(p, i), j, 0, 0))
@@ -147,14 +147,14 @@ def _tall_kinner(a, b, bias, *, bm, bk, act, packed, resident, revisit,
         b_spec = (pl.BlockSpec((k, n), lambda p, i, j: (0, 0)) if resident
                   else pl.BlockSpec((bk, n), lambda p, i, j: (j, 0)))
         o_spec = pl.BlockSpec((bm, n), lambda p, i, j: (row(p, i), 0))
-        bias_spec = pl.BlockSpec((n,), lambda p, i, j: (0,))
+        bias_spec = pl.BlockSpec((1, n), lambda p, i, j: (0, 0))
     in_specs = [a_spec, b_spec]
     args = [a, b]
     has_bias = bias is not None
     if has_bias:
         assert bias.shape == (n,), (bias.shape, n)
         in_specs.append(bias_spec)
-        args.append(bias)
+        args.append(bias.reshape(1, n))
 
     def kernel(*refs):
         a_ref, b_ref = refs[0], refs[1]
@@ -333,8 +333,8 @@ def _skinny_kinner(x, w, bias, *, bk, bn, act, natural, resident, revisit,
     has_bias = bias is not None
     if has_bias:
         assert bias.shape == (n,), (bias.shape, n)
-        in_specs.append(pl.BlockSpec((bn,), lambda i, j: (i,)))
-        args.append(bias)
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j: (0, i)))
+        args.append(bias.reshape(1, n))
 
     def kernel(*refs):
         x_ref, w_ref = refs[0], refs[1]

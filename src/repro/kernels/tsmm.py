@@ -28,12 +28,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.hw import VMEM_LIMIT_BYTES
+
 
 def _compiler_params(dimension_semantics):
-    try:
-        return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-    except (AttributeError, TypeError):  # older naming
-        return pltpu.TPUCompilerParams(dimension_semantics=dimension_semantics)
+    """Mosaic parameters of every kernel in this package: the grid's
+    dimension semantics and the scoped-VMEM limit.  The limit is the
+    same number the planner's feasibility gate budgets against
+    (``core/hw.py``); without it Mosaic applies its 16 MiB default and
+    refuses plans the planner admitted."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _semantics(dims, default: tuple) -> tuple:
@@ -55,10 +60,12 @@ def _m_split_of(nm: int, m_split: int) -> int:
     return ms
 
 
+# Bias operands enter every kernel as (1, n) rows: Mosaic tiles a rank-1
+# bf16 block only in multiples of 256 lanes, a (1, bn) block in 128s.
 def _epilogue(acc, bias_ref, act):
     out = acc
     if bias_ref is not None:
-        out = out + bias_ref[...].astype(jnp.float32)[None, :]
+        out = out + bias_ref[...].astype(jnp.float32)     # (1, n) block
     if act == "relu":
         out = jnp.maximum(out, 0)
     elif act == "silu":
@@ -125,17 +132,17 @@ def tsmm_tall_a(a, b, bias=None, *, bm: int, bk: int, act=None,
         in_specs = [pl.BlockSpec((bm, bk), lambda i, j: (i, j)),
                     pl.BlockSpec((bk, n), lambda i, j: (j, 0))]
         o_spec = pl.BlockSpec((bm, n), lambda i, j: (i, 0))
-        bias_spec = pl.BlockSpec((n,), lambda i, j: (0,))
+        bias_spec = pl.BlockSpec((1, n), lambda i, j: (0, 0))
     else:
         in_specs = [pl.BlockSpec((bm, bk), lambda p, i, j: (row(p, i), j)),
                     pl.BlockSpec((bk, n), lambda p, i, j: (j, 0))]
         o_spec = pl.BlockSpec((bm, n), lambda p, i, j: (row(p, i), 0))
-        bias_spec = pl.BlockSpec((n,), lambda p, i, j: (0,))
+        bias_spec = pl.BlockSpec((1, n), lambda p, i, j: (0, 0))
     args = [a, b]
     if bias is not None:
         assert bias.shape == (n,), (bias.shape, n)
         in_specs.append(bias_spec)
-        args.append(bias)
+        args.append(bias.reshape(1, n))
         kernel = functools.partial(_tall_a_kernel, nk=nk, k_axis=k_axis,
                                    act=act)
     else:
@@ -194,18 +201,18 @@ def tsmm_packed_a(ap, b, bias=None, *, act=None, interpret: bool = False,
         in_specs = [pl.BlockSpec((1, 1, bm, bk), lambda i, j: (i, j, 0, 0)),
                     pl.BlockSpec((bk, n), lambda i, j: (j, 0))]
         o_spec = pl.BlockSpec((bm, n), lambda i, j: (i, 0))
-        bias_spec = pl.BlockSpec((n,), lambda i, j: (0,))
+        bias_spec = pl.BlockSpec((1, n), lambda i, j: (0, 0))
     else:
         in_specs = [pl.BlockSpec((1, 1, bm, bk),
                                  lambda p, i, j: (row(p, i), j, 0, 0)),
                     pl.BlockSpec((bk, n), lambda p, i, j: (j, 0))]
         o_spec = pl.BlockSpec((bm, n), lambda p, i, j: (row(p, i), 0))
-        bias_spec = pl.BlockSpec((n,), lambda p, i, j: (0,))
+        bias_spec = pl.BlockSpec((1, n), lambda p, i, j: (0, 0))
     args = [ap, b]
     if bias is not None:
         assert bias.shape == (n,), (bias.shape, n)
         in_specs.append(bias_spec)
-        args.append(bias)
+        args.append(bias.reshape(1, n))
         kernel = functools.partial(_packed_a_kernel, nk=nk, k_axis=k_axis,
                                    act=act)
     else:
@@ -310,8 +317,8 @@ def tsmm_skinny_a(x, wp, bias=None, *, act=None, interpret: bool = False,
     args = [x, wp]
     if bias is not None:
         assert bias.shape == (n,), (bias.shape, n)
-        in_specs.append(pl.BlockSpec((bn,), lambda i, j: (i,)))
-        args.append(bias)
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j: (0, i)))
+        args.append(bias.reshape(1, n))
         kernel = functools.partial(_skinny_a_kernel, nk=nk, act=act)
     else:
         kernel = functools.partial(_skinny_a_kernel_nobias, nk=nk, act=act)

@@ -42,7 +42,36 @@ import numpy as np
 from repro.configs.base import get_config, get_reduced_config
 from repro.models.registry import build_model
 from repro.serve.engine import Engine
+from repro.serve.programs import enable_compilation_cache
 from repro.serve.scheduler import Request
+
+
+def init_params(model, seed: int = 0):
+    """Random weights from ``seed`` as ``(params, axes)``.  Built in ONE
+    jitted program: eager init would materialize every layer before
+    stacking them, twice the weights in device memory at the peak.  The
+    key uses the hardware bit generator: the threefry program for a
+    model's worth of weights takes minutes to compile."""
+    captured = {}
+
+    def init(rng):
+        params, axes = model.init(rng)
+        captured["axes"] = axes     # pure python, safe to keep from tracing
+        return params
+
+    params = jax.jit(init)(jax.random.key(seed, impl="unsafe_rbg"))
+    return params, captured["axes"]
+
+
+def serving_max_len(trace: list, steps: int, *, ragged: bool) -> int:
+    """Cache capacity for a trace of ``(group size, prompt length)``
+    entries.  Ragged serving runs the continuous-batching pool on one
+    global clock: the base length bucket plus every decode step of every
+    request.  An aligned group needs its prompt plus its steps."""
+    max_prompt = max(p for _, p in trace)
+    if ragged:
+        return 2 * max_prompt + sum(b * steps for b, _ in trace) + 8
+    return max_prompt + steps + 8
 
 
 def make_group(cfg, b: int, prompt_len: int) -> dict:
@@ -116,7 +145,8 @@ def main():
                          "§13); fails if the host has too few devices")
     ap.add_argument("--program-cache", default="",
                     help="program-cache dir override ('off' disables "
-                         "persistence; default REPRO_PROGRAM_CACHE)")
+                         "persistence; default REPRO_PROGRAM_CACHE, else "
+                         "under the compilation cache's directory)")
     ap.add_argument("--background-tune", action="store_true",
                     help="on registry miss, serve off the calibrated-model "
                          "plan and wall-clock + commit the measured winner "
@@ -135,6 +165,7 @@ def main():
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO)
+    enable_compilation_cache()
     if args.find_db:
         from repro.tuning.find_db import attach
         attach(args.find_db)
@@ -148,19 +179,14 @@ def main():
                 overrides[k] = int(v)
         cfg = cfg.reduced(**overrides)
     model = build_model(cfg)
-    params, axes = model.init(jax.random.PRNGKey(0))
 
     trace = parse_trace(args.trace, args.prompt_len) or [(args.batch,
                                                           args.prompt_len)]
     max_batch = args.max_batch or max(b for b, _ in trace)
     max_prompt = max(p for _, p in trace)
     ragged = args.queue or len({p for _, p in trace}) > 1
-    if args.async_mode or ragged:
-        # global-clock capacity: base length bucket + every decode step
-        total_steps = sum(b * args.steps for b, _ in trace)
-        max_len = args.max_len or (2 * max_prompt + total_steps + 8)
-    else:
-        max_len = args.max_len or (max_prompt + args.steps + 8)
+    max_len = args.max_len or serving_max_len(
+        trace, args.steps, ragged=args.async_mode or ragged)
 
     mesh = opts = None
     if args.mesh:
@@ -174,10 +200,13 @@ def main():
             a for a in ("pod", "data") if a in mesh.shape))
     program_cache = (False if args.program_cache.lower() in ("off", "0", "none")
                      else args.program_cache) if args.program_cache else None
-    eng = Engine(model, params, axes, max_len=max_len, max_batch=max_batch,
-                 max_prompt=max_prompt, prepack=not args.no_prepack,
+    # the engine takes the only reference to the weights: each unpacked
+    # leaf is freed as soon as its packed copy exists
+    eng = Engine(model, *init_params(model), max_len=max_len,
+                 max_batch=max_batch, max_prompt=max_prompt,
+                 prepack=not args.no_prepack,
                  background_tune=args.background_tune, mesh=mesh, opts=opts,
-                 program_cache=program_cache)
+                 program_cache=program_cache, donate_params=True)
     print(f"buckets={eng.buckets} length_buckets={eng.grid.length} "
           f"packed_leaves={len(eng.pack_report)}"
           + (f" mesh={dict(mesh.shape)}" if mesh is not None else ""))

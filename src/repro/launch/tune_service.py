@@ -25,14 +25,19 @@ is configured by pointing every process at one shared directory.
 ``work --workers N`` forks N copies of this module (one worker per
 process) so claims exercise the real cross-process lock; a worker
 process that dies mid-lease (crash, OOM, kill) is healed by lease
-expiry — the next claimer requeues its job.
+expiry — the next claimer requeues its job.  A TPU chip belongs to one
+process at a time, so on a TPU host each worker is bound to its own chip
+and more workers than chips are refused.  The parent never initialises
+JAX: it would hold the chips its workers need.
 """
 
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import logging
+import os
 import subprocess
 import sys
 
@@ -64,7 +69,25 @@ def _work_one(args) -> int:
     return 0 if report.failed == 0 else 2
 
 
+def local_tpu_chips() -> int:
+    """TPU chips this host's workers would use, counted from their device
+    files (``/dev/vfio/<n>`` on v5e and later, ``/dev/accel<n>`` before)
+    so that the caller stays off JAX; 0 where JAX is held to another
+    platform."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return (len(glob.glob("/dev/vfio/[0-9]*"))
+            or len(glob.glob("/dev/accel[0-9]*")))
+
+
 def cmd_work(args) -> int:
+    chips = local_tpu_chips()
+    if chips and args.workers > chips:
+        raise SystemExit(
+            f"work --workers {args.workers}: this host has {chips} TPU "
+            f"chip(s), and a chip belongs to one worker process; run at "
+            f"most {chips} workers")
     if args.workers <= 1:
         return _work_one(args)
     cmd = [sys.executable, "-m", "repro.launch.tune_service", "work",
@@ -76,12 +99,24 @@ def cmd_work(args) -> int:
         cmd += ["--queue", args.queue]
     if args.max_jobs:
         cmd += ["--max-jobs", str(args.max_jobs)]
-    procs = [subprocess.Popen(cmd) for _ in range(args.workers)]
+    procs = [subprocess.Popen(cmd, env=_worker_env(i, chips))
+             for i in range(args.workers)]
     rcs = [p.wait() for p in procs]
     q = _queue(args)
     print("fleet: " + json.dumps({"workers": args.workers,
                                   "exit_codes": rcs, **q.status()}))
     return 0 if all(rc == 0 for rc in rcs) else 2
+
+
+def _worker_env(i: int, chips: int) -> dict:
+    """Environment of worker ``i``: on a TPU host, bound to chip ``i``
+    alone (a process otherwise claims every chip of the host)."""
+    env = dict(os.environ)
+    if chips:
+        env.update(TPU_VISIBLE_CHIPS=str(i),
+                   TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                   TPU_PROCESS_BOUNDS="1,1,1")
+    return env
 
 
 def cmd_export(args) -> int:

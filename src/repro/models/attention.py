@@ -17,10 +17,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.linear import linear
+from repro.core.linear import in_serving_ctx, linear
 from repro.models.layers import apply_rope, rope_tables
 from repro.models.param import ParamTree
-from repro.sharding.context import shard_act
+from repro.sharding.context import get_ctx, shard_act
 
 NEG_INF = -1e30
 
@@ -57,6 +57,35 @@ def _chunk_body(q, k, v, q_pos, k_pos, scale, window, causal, valid_from=None):
     return jnp.where(mask[:, None, None], s, NEG_INF)
 
 
+def _flash(q, k, v, causal: bool):
+    """The Pallas flash kernel on (B, S, H, D) operands; GQA KV heads are
+    repeated up to H first."""
+    from repro.kernels.flash_attention import flash_attention
+    g = q.shape[2] // k.shape[2]
+    kr = jnp.repeat(k, g, axis=2) if g > 1 else k
+    vr = jnp.repeat(v, g, axis=2) if g > 1 else v
+    out = flash_attention(q.transpose(0, 2, 1, 3), kr.transpose(0, 2, 1, 3),
+                          vr.transpose(0, 2, 1, 3), causal=causal)
+    return out.transpose(0, 2, 1, 3)
+
+
+def _flash_per_shard(q, k, v, causal: bool):
+    """:func:`_flash` under the ambient mesh, one kernel per shard (XLA
+    cannot partition a Mosaic kernel).  None when the query and KV heads
+    are not sharded alike: a shard would then pair its query heads with
+    the wrong KV group."""
+    ctx = get_ctx()
+    if ctx is None:
+        return _flash(q, k, v, causal)
+    qs = ctx.spec_for(("batch", None, "heads", None), q.shape)
+    ks = ctx.spec_for(("batch", None, "kvheads", None), k.shape)
+    if qs != ks:
+        return None
+    return jax.shard_map(functools.partial(_flash, causal=causal),
+                         mesh=ctx.mesh, in_specs=(qs, ks, ks), out_specs=qs,
+                         check_vma=False)(q, k, v)
+
+
 def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
                       chunk: int = 512, q_offset=0, k_offset=None,
                       valid_from=None):
@@ -72,26 +101,23 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
     ``valid_from``: (B,) absolute first-real-token position per row
     (left-pad masking); ``q_offset`` may be traced under jit.
 
-    On TPU, full-window self-attention dispatches to the fused Pallas
-    flash kernel (kernels/flash_attention.py): scores stay in VMEM and
-    above-diagonal blocks are skipped — the jnp path below is the CPU /
-    SWA / cross-attention / ragged fallback and the kernel's oracle.
+    On TPU, full-window self-attention traced for serving
+    (``core.linear.serving_ctx``) dispatches to the fused Pallas flash
+    kernel (kernels/flash_attention.py): scores stay in VMEM and
+    above-diagonal blocks are skipped.  The kernel has no differentiation
+    rule, so training stays on the jnp path below, which is also the CPU /
+    SWA / cross-attention / ragged path and the kernel's oracle.
     """
     if k_offset is None:
         k_offset = q_offset
-    if (jax.default_backend() == "tpu" and window == 0
+    if (in_serving_ctx() and jax.default_backend() == "tpu" and window == 0
             and isinstance(q_offset, int) and q_offset == 0
             and isinstance(k_offset, int) and k_offset == 0
             and valid_from is None
             and q.shape[1] == k.shape[1] and q.shape[1] % 256 == 0):
-        from repro.kernels.flash_attention import flash_attention
-        g = q.shape[2] // k.shape[2]
-        kr = jnp.repeat(k, g, axis=2) if g > 1 else k
-        vr = jnp.repeat(v, g, axis=2) if g > 1 else v
-        out = flash_attention(q.transpose(0, 2, 1, 3),
-                              kr.transpose(0, 2, 1, 3),
-                              vr.transpose(0, 2, 1, 3), causal=causal)
-        return out.transpose(0, 2, 1, 3)
+        out = _flash_per_shard(q, k, v, causal)
+        if out is not None:
+            return out
     b, sq, h, d = q.shape
     sk, kh = k.shape[1], k.shape[2]
     dv = v.shape[-1]          # may differ from d (MLA: dk=nope+rope, dv=v)

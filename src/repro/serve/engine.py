@@ -43,7 +43,8 @@ from repro.serve.clock import StepCost, ensure_clock
 from repro.serve.programs import ProgramStore
 from repro.models.param import is_axes_leaf
 from repro.sharding.context import sharding_ctx
-from repro.sharding.rules import ShardingOptions, axis_size, pspec_for
+from repro.sharding.rules import (ShardingOptions, axis_size, packed_pspec,
+                                  pspec_for)
 
 log = logging.getLogger(__name__)
 
@@ -95,12 +96,16 @@ def iter_packable(params, axes, mesh=None,
 
 
 def pack_tree_for_serving(params, axes, batch_m, mesh=None,
-                          opts: Optional[ShardingOptions] = None):
+                          opts: Optional[ShardingOptions] = None, *,
+                          donate: bool = False):
     """Replace packable weight leaves with planned PackedTensors.
 
     ``batch_m``: the serving batch size, or a tuple of batch buckets — with
     buckets the chosen blocks conform to every bucket (DESIGN.md §7) so one
-    packed tree serves all of them.
+    packed tree serves all of them.  ``donate`` deletes each unpacked leaf
+    as soon as its packed copy exists, so the device holds one tree plus
+    one leaf at the peak instead of both trees (the caller's ``params``
+    must not be used afterwards).
     Returns (packed_params, report: {path: blocks_shape}).
     """
     opts = opts or ShardingOptions()
@@ -120,6 +125,11 @@ def pack_tree_for_serving(params, axes, batch_m, mesh=None,
         if pk is None:
             return p
         report["/".join(path)] = tuple(pk.blocks.shape)
+        if mesh is not None:
+            spec = packed_pspec(a, pk, mesh, opts)
+            pk.shard_axes = (spec[-4], spec[-3])
+        if donate:
+            p.delete()
         return pk
 
     from repro.core import registry
@@ -239,6 +249,10 @@ class Engine:
     all closing over the same packed param tree.  A legacy fixed-batch
     caller (``batch_size=N``) gets the full bucket set; pass
     ``buckets=(N,)`` to pin single-bucket planning/packing.
+
+    ``donate_params=True`` hands the engine the caller's weights: each
+    unpacked leaf is deleted once its packed copy exists, which is what
+    lets a model whose weights fill most of the device be packed at all.
     """
 
     def __init__(self, model, params, axes, *, max_len: int,
@@ -250,7 +264,8 @@ class Engine:
                  prepack: bool = True, background_tune: bool = False,
                  tuner_opts: Optional[dict] = None, tune_queue=None,
                  program_cache=None,
-                 clock=None, step_cost: Optional[StepCost] = None):
+                 clock=None, step_cost: Optional[StepCost] = None,
+                 donate_params: bool = False):
         if max_batch is None:
             max_batch = batch_size
         self.model = model
@@ -285,7 +300,7 @@ class Engine:
             # rank against the measurement-calibrated model, and missed
             # problems get wall-clocked + committed off-thread below
             from repro.core import autotuner, evaluator
-            hw = evaluator.calibrated_hw()
+            hw = evaluator.calibrated_hw(autotuner.default_hw())
             autotuner.set_default_hw(hw)
             self.tuner = _BackgroundTuner(hw, queue=tune_queue,
                                           **(tuner_opts or {}))
@@ -313,7 +328,8 @@ class Engine:
         if prepack:
             with degrade.use(self.degrade):
                 params, report = pack_tree_for_serving(
-                    params, axes, self.buckets, mesh, self.opts)
+                    params, axes, self.buckets, mesh, self.opts,
+                    donate=donate_params)
             log.info("pre-packed %d weight leaves for buckets %s",
                      len(report), self.buckets)
             self.pack_report = report

@@ -59,9 +59,10 @@ log = logging.getLogger(__name__)
 # bump when the on-disk payload layout changes
 PROGRAM_SCHEMA = 1
 
-# donated argument positions per program kind (cache buffers are reused
-# in place — the same donation the old jit wrappers declared)
-DONATE = {"prefill": (), "decode": (1,), "prefill_row": (2,)}
+# donated argument positions per program kind: cache buffers are reused
+# in place (prefill fills the fresh cache it is handed, so the input and
+# output caches never both occupy the device)
+DONATE = {"prefill": (2,), "decode": (1,), "prefill_row": (2,)}
 
 # batch-dict leaf -> logical activation axes (ShardCtx placement)
 BATCH_AXES = {"tokens": ("batch", "seq"), "pad": ("batch",),
@@ -69,18 +70,40 @@ BATCH_AXES = {"tokens": ("batch", "seq"), "pad": ("batch",),
               "enc_frames": ("batch", "seq", "embed")}
 
 
+# Fixed in-checkout cache root (git-ignored) for when the environment
+# names no compilation-cache directory.  A fixed path, because the path
+# is part of what a cache is found by: a directory that moves never hits.
+CHECKOUT_CACHE = Path(__file__).resolve().parents[3] / ".cache"
+
+
+def enable_compilation_cache() -> Path:
+    """Turn on JAX's persistent compilation cache and return its
+    directory; entry points call this when they start (never at import).
+    When ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and no
+    other directory is set here; else ``.cache/jax`` in the checkout."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    path = Path(env) if env else CHECKOUT_CACHE / "jax"
+    if not env:
+        jax.config.update("jax_compilation_cache_dir", str(path))
+    # cache every program: the install sweep compiles many small kernels
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
+
+
 def program_cache_dir() -> Optional[Path]:
     """Resolve the persistent program-cache directory.
 
     ``REPRO_PROGRAM_CACHE``: a path, or ``off``/``0``/``none`` to disable
-    persistence entirely.  Unset -> ``~/.cache/repro/programs`` (sibling
-    of the plan registry)."""
+    persistence entirely.  Unset -> ``repro-programs`` under
+    ``JAX_COMPILATION_CACHE_DIR`` when that is set, else
+    ``.cache/programs`` in the checkout."""
     raw = os.environ.get("REPRO_PROGRAM_CACHE", "")
     if raw:
         if raw.lower() in ("off", "0", "none"):
             return None
         return Path(raw)
-    return Path(os.environ.get("HOME", "/tmp")) / ".cache" / "repro" / "programs"
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    return Path(env) / "repro-programs" if env else CHECKOUT_CACHE / "programs"
 
 
 _CODE_FP: Optional[str] = None
@@ -203,8 +226,10 @@ class ProgramStore:
                              + code_fingerprint()
                              + _grammar.GRAMMAR_VERSION)
         self._programs: dict[str, Program] = {}
+        # compile_s covers trace + lower + XLA compile; lower_s is its
+        # trace + lower part, which JAX's persistent cache cannot skip
         self._stats = {"traced": 0, "from_disk": 0, "reused": 0,
-                       "compile_s": 0.0, "load_s": 0.0}
+                       "compile_s": 0.0, "lower_s": 0.0, "load_s": 0.0}
 
     # -- keys ------------------------------------------------------------
 
@@ -294,10 +319,11 @@ class ProgramStore:
             in_sh, out_sh = self.shardings_for(kind, args)
             from repro.core.linear import serving_ctx
             with serving_ctx(), sharding_ctx(self.lowering_mesh, self.opts):
-                compiled = aot_lower(
+                lowered = aot_lower(
                     self._fns[kind], structs, in_shardings=in_sh,
-                    out_shardings=out_sh,
-                    donate_argnums=DONATE[kind]).compile()
+                    out_shardings=out_sh, donate_argnums=DONATE[kind])
+                self._stats["lower_s"] += time.perf_counter() - t0
+                compiled = lowered.compile()
             self._save(key, kind, compiled)
         dt = time.perf_counter() - t0
         self._stats["traced" if source == "traced" else "from_disk"] += 1
@@ -323,7 +349,12 @@ class ProgramStore:
                                                   path.read_bytes()))
             if rec.get("schema") != PROGRAM_SCHEMA:
                 return None
-            return se.deserialize_and_load(*rec["payload"])
+            # bind to the devices the program was compiled for: left
+            # unset, the loader would spread it over every local device
+            devs = (list(self.mesh.devices.flat) if self.mesh is not None
+                    else jax.devices()[:1])
+            return se.deserialize_and_load(*rec["payload"],
+                                           execution_devices=devs)
         except Exception as e:  # noqa: BLE001 — any failure = recompile
             log.warning("program cache: dropping unreadable %s (%s)",
                         path.name, e)
@@ -360,6 +391,11 @@ class ProgramStore:
         out["programs"] = len(self._programs)
         out["cache_dir"] = str(self.cache_dir) if self.cache_dir else None
         return out
+
+    def handles(self) -> list:
+        """Every program this store has handed out (first acquisition
+        each), for HLO inspection."""
+        return list(self._programs.values())
 
     def report(self) -> list:
         """Per-program rows (key, kind, source, acquire seconds) — the

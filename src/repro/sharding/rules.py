@@ -94,7 +94,7 @@ def pspec_for(axes: tuple, shape: tuple, mesh: Mesh, opts: ShardingOptions) -> P
     return P(*assign)
 
 
-def _packed_pspec(axes: tuple, leaf, mesh: Mesh, opts: ShardingOptions) -> P:
+def packed_pspec(axes: tuple, leaf, mesh: Mesh, opts: ShardingOptions) -> P:
     """Spec for a PackedTensor leaf: the logical (row, col) assignment moves
     to the block-count dims (n0, n1); block dims and lead dims replicate.
     The fit check runs on block counts (count per shard >= 1, divisible)."""
@@ -138,7 +138,7 @@ def param_pspecs(axes_tree, shapes_tree, mesh: Mesh, opts: ShardingOptions):
 
     def one(axes, leaf):
         if is_packed(leaf):
-            return _packed_pspec(axes, leaf, mesh, opts)
+            return packed_pspec(axes, leaf, mesh, opts)
         return pspec_for(axes, leaf.shape, mesh, opts)
 
     return jax.tree.map(one, axes_tree, shapes_tree, is_leaf=is_axes_leaf)

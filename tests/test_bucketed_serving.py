@@ -209,6 +209,7 @@ def test_sharded_install_then_mesh_engine_all_hit():
         from repro.configs import get_reduced_config
         from repro.core import registry
         from repro.core.install import install_arch, sharded_serving_shapes
+        from repro.launch.mesh import make_mesh
         from repro.core.plan import buckets_for
         from repro.models.registry import build_model
         from repro.serve.engine import Engine
@@ -216,7 +217,7 @@ def test_sharded_install_then_mesh_engine_all_hit():
         cfg = get_reduced_config("qwen1_5_4b").reduced(
             d_model=512, d_ff=1024, num_layers=2, vocab_size=1024,
             num_heads=8, num_kv_heads=8, head_dim=64)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         sharded = sharded_serving_shapes(cfg, mesh)
         assert any(s > 1 for _, _, s in sharded), sharded
         registry.clear_memory()

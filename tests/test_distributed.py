@@ -22,6 +22,7 @@ def run_sub(body: str, timeout=900) -> str:
         os.environ["REPRO_PLAN_CACHE"] = "/tmp/repro_sub_plans.json"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=SRC)
     out = subprocess.run([sys.executable, "-c", code], capture_output=True,
@@ -34,7 +35,7 @@ def test_distributed_tsmm_no_collectives_and_correct():
     out = run_sub("""
         from repro.core import tsmm as T
         from repro.kernels import ref
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         a = jnp.asarray(rng.standard_normal((1024, 512)), jnp.float32)
         b = jnp.asarray(rng.standard_normal((512, 16)), jnp.float32)
@@ -56,7 +57,7 @@ def test_conventional_ksplit_has_allreduce():
     out = run_sub("""
         from repro.core import tsmm as T
         from repro.kernels import ref
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(0)
         a = jnp.asarray(rng.standard_normal((256, 1024)), jnp.float32)
         b = jnp.asarray(rng.standard_normal((1024, 16)), jnp.float32)
@@ -74,7 +75,7 @@ def test_overlapped_ring_tsmm_correct():
     out = run_sub("""
         from repro.core import tsmm as T
         from repro.kernels import ref
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         rng = np.random.default_rng(1)
         a = jnp.asarray(rng.standard_normal((128, 1024)), jnp.float32)
         b = jnp.asarray(rng.standard_normal((1024, 32)), jnp.float32)
@@ -86,6 +87,40 @@ def test_overlapped_ring_tsmm_correct():
         print("OK ring tsmm correct + ppermute present")
     """)
     assert "OK ring" in out
+
+
+def test_packed_kernel_runs_per_shard_under_mesh():
+    """XLA cannot partition a Mosaic kernel, so under a serving mesh a
+    packed weight's kernel runs once per shard: column-sharded blocks
+    give each device its own output columns, row-sharded blocks sum f32
+    partials across the axis.  Both match the unsharded product."""
+    out = run_sub("""
+        from repro.core.packing import pack
+        from repro.core.tsmm import tsmm_dot
+        from repro.sharding.context import sharding_ctx
+        from repro.sharding.rules import ShardingOptions
+        mesh = make_mesh((4,), ("model",))
+        rng = np.random.default_rng(0)
+        x = jnp.asarray(rng.standard_normal((8, 1024)), jnp.float32)
+        w = jnp.asarray(rng.standard_normal((1024, 512)), jnp.float32)
+        bias = jnp.asarray(rng.standard_normal((512,)), jnp.float32)
+        want = np.asarray(jax.nn.silu(x @ w + bias))
+        fn = jax.jit(lambda x, w, b: tsmm_dot(x, w, bias=b, act="silu",
+                                              impl="pallas_interpret"))
+        for axes in (("model", None), (None, "model")):
+            pk = pack(w, 256, 128)
+            pk.shard_axes = axes
+            pk.blocks = jax.device_put(
+                pk.blocks, NamedSharding(mesh, P(*axes, None, None)))
+            with sharding_ctx(mesh, ShardingOptions(dp_axes=())):
+                got = np.asarray(fn(x, pk, bias))
+                txt = fn.lower(x, pk, bias).compile().as_text()
+            err = float(np.abs(got - want).max())
+            assert err < 1e-3 * float(np.abs(want).max()), (axes, err)
+            assert ("all-reduce" in txt) == (axes[0] is not None), axes
+        print("OK per-shard packed kernel")
+    """)
+    assert "OK per-shard packed kernel" in out
 
 
 def test_sharded_train_step_runs_and_matches_single():
@@ -103,7 +138,7 @@ def test_sharded_train_step_runs_and_matches_single():
             num_heads=4, num_kv_heads=2, head_dim=32)
         model = build_model(cfg)
         ocfg = OptConfig(warmup_steps=0, decay_steps=10)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         opts = ShardingOptions(dp_axes=("data",), fsdp=True)
         batch = {"tokens": (jnp.arange(8*32).reshape(8, 32) % 512).astype(jnp.int32),
                  "labels": (jnp.arange(8*32).reshape(8, 32) % 512).astype(jnp.int32)}
@@ -155,8 +190,8 @@ def test_dryrun_cell_on_8_devices():
         import tempfile, json
         dr.ART_DIR = Path(tempfile.mkdtemp())
         import repro.launch.mesh as lm
-        lm.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
-            (2, 2, 2), ("pod", "data", "model")) if multi_pod else jax.make_mesh((4, 2), ("data", "model"))
+        lm.make_production_mesh = lambda multi_pod=False: make_mesh(
+            (2, 2, 2), ("pod", "data", "model")) if multi_pod else make_mesh((4, 2), ("data", "model"))
         rec = dr.run_cell("whisper_base", "train_4k", "single", force=True)
         assert rec["cost_analysis"].get("flops", 0) > 0
         assert "jaxpr_cost" in rec and rec["jaxpr_cost"]["flops"] > 0

@@ -248,7 +248,7 @@ def test_schedule_hypothesis_feasibility_property():
     from hypothesis import given, settings
     from hypothesis import strategies as st
 
-    from repro.core.hw import VMEM_USABLE_FRACTION
+    from repro.core.hw import VMEM_LIMIT_BYTES
 
     kernels = st.sampled_from(
         [KernelSpec(), KernelSpec.make("ksplit", splits=2),
@@ -273,8 +273,7 @@ def test_schedule_hypothesis_feasibility_property():
             return
         # every gate must actually hold for an admitted plan
         assert 2 <= multibuffer <= 4 and m_split >= 1
-        assert vmem_bytes_needed(plan, TPU_V5E) <= \
-            TPU_V5E.vmem_bytes * VMEM_USABLE_FRACTION
+        assert vmem_bytes_needed(plan, TPU_V5E) <= VMEM_LIMIT_BYTES
         if m_split > 1:
             assert kernel.name in M_SPLIT_KERNELS
             assert plan.grid[0] % m_split == 0

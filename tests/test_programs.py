@@ -69,6 +69,14 @@ def test_env_cache_dir_resolution(monkeypatch):
     assert program_cache_dir() == Path("/tmp/somewhere")
     monkeypatch.setenv("REPRO_PROGRAM_CACHE", "off")
     assert program_cache_dir() is None
+    # unset: under the compilation cache's directory when one is named,
+    # else at the fixed in-checkout path
+    monkeypatch.delenv("REPRO_PROGRAM_CACHE")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/jaxcache")
+    assert program_cache_dir() == Path("/tmp/jaxcache/repro-programs")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = Path(__file__).resolve().parents[1]
+    assert program_cache_dir() == checkout / ".cache" / "programs"
 
 
 # ---------------------------------------------------------------------------
@@ -201,7 +209,8 @@ def test_install_precompile_then_engine_restart_subprocess(tmp_path):
     first traffic with zero trace-time programs."""
     env = dict(os.environ, PYTHONPATH=SRC,
                REPRO_PROGRAM_CACHE=str(tmp_path / "programs"),
-               REPRO_PLAN_CACHE=str(tmp_path / "plans.json"))
+               REPRO_PLAN_CACHE=str(tmp_path / "plans.json"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax"))
 
     install = subprocess.run(
         [sys.executable, "-m", "repro.core.install", "--precompile",

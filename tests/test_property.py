@@ -13,7 +13,7 @@ from hypothesis import given, settings
 
 from repro.core import registry
 from repro.core.autotuner import candidate_blocks, make_plan
-from repro.core.hw import TPU_V5E, VMEM_USABLE_FRACTION
+from repro.core.hw import VMEM_LIMIT_BYTES
 from repro.core.plan import BucketGrid, Problem, is_tsmm
 from repro.core.vmem_model import feasible, vmem_bytes_needed
 from repro.kernels import ops, ref
@@ -40,8 +40,7 @@ problem_st = st.builds(
 def test_candidates_respect_vmem_bound(problem):
     for plan in candidate_blocks(problem):
         assert feasible(plan)
-        assert (vmem_bytes_needed(plan)
-                <= TPU_V5E.vmem_bytes * VMEM_USABLE_FRACTION)
+        assert vmem_bytes_needed(plan) <= VMEM_LIMIT_BYTES
         # MXU alignment (the register-blocking analogue)
         assert plan.bk % 128 == 0 and plan.bn % 128 == 0
         # grid covers the problem

@@ -25,7 +25,7 @@ def test_serve_2d_tp_reduces_collectives_on_8dev():
             d_model=512, d_ff=1024, num_layers=2, vocab_size=1024,
             num_heads=8, num_kv_heads=2, head_dim=64)
         model = build_model(cfg)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
 
         def lower_decode(opts):
             with sharding_ctx(mesh, opts):
@@ -103,7 +103,7 @@ def test_ckpt_restores_onto_different_mesh():
         mgr = CheckpointManager(d, async_save=False)
         mgr.save(7, tree)
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         sh = {"w": NamedSharding(mesh, P("data", None)),
               "b": NamedSharding(mesh, P(None))}
         got = mgr.restore(7, jax.eval_shape(lambda: tree), shardings=sh)

@@ -30,6 +30,7 @@ def run_sub(body: str, timeout=900) -> str:
         os.environ.setdefault("REPRO_PROGRAM_CACHE", "off")
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_reduced_config
         from repro.models.registry import build_model
         from repro.serve.engine import Engine
@@ -38,7 +39,7 @@ def run_sub(body: str, timeout=900) -> str:
 
         cfg = get_reduced_config("qwen1_5_4b").reduced(dtype="float32")
         params, axes = build_model(cfg).init(jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((2,), ("model",))
+        mesh = make_mesh((2,), ("model",))
         opts = ShardingOptions(dp_axes=())
         eng = Engine(build_model(cfg), params, axes, max_len=64,
                      buckets=(1, 2), max_prompt=16, mesh=mesh, opts=opts)
@@ -127,11 +128,12 @@ def test_sharded_precompile_restart_zero_traces(tmp_path):
         from repro.configs import get_reduced_config
         from repro.models.registry import build_model
         from repro.serve.engine import Engine
+        from repro.launch.mesh import make_mesh
         from repro.sharding.rules import ShardingOptions
 
         cfg = get_reduced_config("qwen1_5_4b").reduced(dtype="float32")
         params, axes = build_model(cfg).init(jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((2,), ("model",))
+        mesh = make_mesh((2,), ("model",))
         opts = ShardingOptions(dp_axes=())
         eng = Engine(build_model(cfg), params, axes, max_len=64,
                      buckets=(2,), max_prompt=16, mesh=mesh, opts=opts)

@@ -307,6 +307,43 @@ def test_three_worker_fleet_measures_each_job_exactly_once(fleet):
         assert plan is not None and plan.chosen_by == "measured", p.key()
 
 
+def test_work_refuses_more_workers_than_tpu_chips(fleet, monkeypatch):
+    """On a host with one TPU chip, two workers would fight over it: the
+    parent refuses before it starts any child."""
+    from repro.launch import tune_service
+    monkeypatch.setattr(tune_service, "local_tpu_chips", lambda: 1)
+
+    def no_child(*a, **k):
+        raise AssertionError("a worker process was started")
+
+    monkeypatch.setattr(tune_service.subprocess, "Popen", no_child)
+    with pytest.raises(SystemExit, match="1 TPU chip"):
+        tune_service.main(["work", "--workers", "2"])
+
+
+def test_work_binds_each_worker_to_its_own_chip(fleet, monkeypatch):
+    from repro.launch import tune_service
+    monkeypatch.setattr(tune_service, "local_tpu_chips", lambda: 2)
+    envs = []
+
+    class FakeProc:
+        def __init__(self, cmd, env):
+            envs.append(env)
+
+        def wait(self):
+            return 0
+
+    monkeypatch.setattr(tune_service.subprocess, "Popen", FakeProc)
+    assert tune_service.main(["work", "--workers", "2"]) == 0
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1"]
+
+
+def test_local_tpu_chips_is_zero_off_tpu(monkeypatch):
+    from repro.launch import tune_service
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert tune_service.local_tpu_chips() == 0
+
+
 def test_crashed_worker_lease_is_requeued_and_completed(fleet):
     q = _seed_jobs([P_SKINNY])
     # worker 1 dies the hard way right after claiming (os._exit)
