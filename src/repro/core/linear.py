@@ -29,6 +29,7 @@ import math
 import threading
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.packing import is_packed
@@ -58,16 +59,22 @@ def in_serving_ctx() -> bool:
     return getattr(_SERVING, "on", False)
 
 
-def linear(x, w, b=None, act: Optional[str] = None):
-    """act(x @ w + b).  ``w``: (k, n) array or PackedTensor."""
-    if is_packed(w):
-        return tsmm_dot(x, w, bias=b, act=act)
-    if (in_serving_ctx() and w.ndim == 2
-            and is_tsmm(math.prod(x.shape[:-1]), *w.shape)):
-        return tsmm_dot(x, w, bias=b, act=act)
-    out = jnp.dot(x, w)
-    if b is not None:
-        out = out + b.astype(out.dtype)
-    if act is not None:
-        out = act_ref(out.astype(jnp.float32), act).astype(x.dtype)
-    return out
+def linear(x, w, b=None, act: Optional[str] = None, *,
+           name: Optional[str] = None):
+    """act(x @ w + b).  ``w``: (k, n) array or PackedTensor.
+
+    ``name`` is the weight's leaf (``wq``, ``w_down``, ...): the call's
+    ops are traced under ``jax.named_scope(name)`` and a planned kernel
+    is named ``tsmm_<name>``, so a profile attributes device time to the
+    leaf."""
+    kernel = f"tsmm_{name}" if name else None
+    with jax.named_scope(name) if name else contextlib.nullcontext():
+        if is_packed(w) or (in_serving_ctx() and w.ndim == 2 and is_tsmm(
+                math.prod(x.shape[:-1]), *w.shape)):
+            return tsmm_dot(x, w, bias=b, act=act, name=kernel)
+        out = jnp.dot(x, w)
+        if b is not None:
+            out = out + b.astype(out.dtype)
+        if act is not None:
+            out = act_ref(out.astype(jnp.float32), act).astype(x.dtype)
+        return out

@@ -202,12 +202,14 @@ def _stamped_spec(b: PackedTensor, m: int) -> tuple:
 
 
 def tsmm_dot(a, b, *, bias=None, act: Optional[str] = None,
-             plan: Optional[Plan] = None, impl: Optional[str] = None):
+             plan: Optional[Plan] = None, impl: Optional[str] = None,
+             name: Optional[str] = None):
     """C = act(A @ B + bias) with TSMM planning.
 
     ``a``: (..., k) activations; ``b``: (k, n) array or PackedTensor.
     Shapes are static under jit, so planning happens at trace time — the
     'runtime stage' of the paper runs once per compiled program.
+    ``name`` names the planned kernel's custom call in the program.
     """
     impl = impl or impl_choice()
     override = variant_choice()
@@ -251,7 +253,7 @@ def tsmm_dot(a, b, *, bias=None, act: Optional[str] = None,
             def run(x, w, bias_, act_):
                 return variants.run_skinny_a(
                     spec, x, w, bias_, act_, bk=bk, bn=bn, packed=True,
-                    impl=use_impl, schedule=sched)
+                    impl=use_impl, schedule=sched, name=name)
             mesh = _mosaic_mesh(use_impl)
             if mesh is not None:
                 out = _skinny_per_shard(mesh, b, run, a2, bias, act)
@@ -278,7 +280,8 @@ def tsmm_dot(a, b, *, bias=None, act: Optional[str] = None,
         def _skinny(use_impl):
             return variants.run_skinny_a(
                 spec, a2, b, bias, act, bk=plan.bk, bn=plan.bn,
-                packed=False, impl=use_impl, schedule=sched)[:, :n]
+                packed=False, impl=use_impl, schedule=sched,
+                name=name)[:, :n]
 
         out = _laddered(
             "skinny", f"skinny_a/{m}x{k}x{n}/{spec.key()}",
@@ -298,10 +301,11 @@ def tsmm_dot(a, b, *, bias=None, act: Optional[str] = None,
                 ap = pack(a2, plan.bm, plan.bk)
                 return variants.run_tall_a(
                     spec, ap.blocks, b, bias, act, bm=plan.bm, bk=plan.bk,
-                    packed=True, impl=use_impl, schedule=sched)[:m, :n]
+                    packed=True, impl=use_impl, schedule=sched,
+                    name=name)[:m, :n]
             return variants.run_tall_a(
                 spec, a2, b, bias, act, bm=plan.bm, bk=plan.bk,
-                packed=False, impl=use_impl, schedule=sched)
+                packed=False, impl=use_impl, schedule=sched, name=name)
 
         out = _laddered(
             "tall", f"tall_a/{m}x{k}x{n}/{spec.key()}",

@@ -113,7 +113,7 @@ def _split_epilogue(out, bias, act):
 
 
 def _tall_kinner(a, b, bias, *, bm, bk, act, packed, resident, revisit,
-                 dims, m_split, interpret):
+                 dims, m_split, interpret, name=None):
     """K-innermost tall-A program for any (bres, acc, fused-epi) choice.
 
     ``resident`` pins the whole B in VMEM (constant index map) and slices
@@ -187,10 +187,12 @@ def _tall_kinner(a, b, bias, *, bm, bk, act, packed, resident, revisit,
                         else [pltpu.VMEM((bm, n), jnp.float32)]),
         compiler_params=_k._compiler_params(_k._semantics(dims, default)),
         interpret=interpret,
+        name=name,
     )(*args)
 
 
-def _tall_ksplit(a, b, *, bm, bk, splits, packed, resident, dims, interpret):
+def _tall_ksplit(a, b, *, bm, bk, splits, packed, resident, dims, interpret,
+                 name=None):
     """K-split tall-A: ``splits`` independent partial sums (one parallel
     grid dim), fp32 partials out (splits, M, N); the caller's
     ``sum(axis=0)`` is the fused reduction.  ``resident`` pins the whole
@@ -241,10 +243,11 @@ def _tall_ksplit(a, b, *, bm, bk, splits, packed, resident, dims, interpret):
         compiler_params=_k._compiler_params(
             _k._semantics(dims, ("parallel", "parallel", "arbitrary"))),
         interpret=interpret,
+        name=name,
     )(a, b)
 
 
-def _tall_kouter(a, b, *, bm, bk, packed, dims, interpret):
+def _tall_kouter(a, b, *, bm, bk, packed, dims, interpret, name=None):
     """K-outermost loop order: each k step sweeps every output row panel,
     accumulating into an fp32 output revisited in HBM.  B's k-block is
     fetched ONCE per k step (vs once per row panel for kinner) at the
@@ -289,6 +292,7 @@ def _tall_kouter(a, b, *, bm, bk, packed, dims, interpret):
         compiler_params=_k._compiler_params(
             _k._semantics(dims, ("arbitrary",))),
         interpret=interpret,
+        name=name,
     )
 
     def step(j, acc):
@@ -308,7 +312,7 @@ def _tall_kouter(a, b, *, bm, bk, packed, dims, interpret):
 
 
 def _skinny_kinner(x, w, bias, *, bk, bn, act, natural, resident, revisit,
-                   dims, interpret):
+                   dims, interpret, name=None):
     """K-innermost skinny-A program.  ``natural`` reads W in its (K, N)
     layout with a strided index map (the packfuse axis — no per-call pack
     pass); ``resident`` pins the whole X row panel (constant map) and
@@ -368,11 +372,12 @@ def _skinny_kinner(x, w, bias, *, bk, bn, act, natural, resident, revisit,
         compiler_params=_k._compiler_params(
             _k._semantics(dims, ("parallel", "arbitrary"))),
         interpret=interpret,
+        name=name,
     )(*args)
 
 
 def _skinny_ksplit(x, w, *, bk, bn, splits, natural, resident, dims,
-                   interpret):
+                   interpret, name=None):
     """K-split skinny-A: fp32 partials out (splits, m, N); caller reduces
     + applies the epilogue.  ``natural`` strides the (K, N) weight
     directly; ``resident`` pins the whole X and slices the group-local k
@@ -423,6 +428,7 @@ def _skinny_ksplit(x, w, *, bk, bn, splits, natural, resident, dims,
         compiler_params=_k._compiler_params(
             _k._semantics(dims, ("parallel", "parallel", "arbitrary"))),
         interpret=interpret,
+        name=name,
     )(x, w)
 
 
@@ -433,9 +439,9 @@ def _skinny_ksplit(x, w, *, bk, bn, splits, natural, resident, dims,
 
 @functools.partial(jax.jit,
                    static_argnames=("g", "bm", "bk", "act", "packed", "impl",
-                                    "dims", "m_split"))
+                                    "dims", "m_split", "name"))
 def _tall_compute(a, b, bias, *, g, bm, bk, act, packed, impl, dims,
-                  m_split):
+                  m_split, name=None):
     """One program per (grammar point, blocks, act, impl, schedule).
     ``bias``/``act`` arrive pre-gated by the wrapper: None for
     ``epi=split`` points (raw output; the wrapper runs the separate
@@ -467,20 +473,20 @@ def _tall_compute(a, b, bias, *, g, bm, bk, act, packed, impl, dims,
     interpret = impl == "pallas_interpret"
     if g.loop == "kouter":
         out = _tall_kouter(a, b, bm=bm, bk=bk, packed=packed, dims=dims,
-                           interpret=interpret)
+                           interpret=interpret, name=name)
         # the epilogue rides the final cast pass over the fp32 accumulator
         # (already charged by the cost model's output-revisit terms)
         return _epilogue_f32(out, bias, act, out_dtype)
     if g.ksplit > 1:
         parts = _tall_ksplit(a, b, bm=bm, bk=bk, splits=g.ksplit,
                              packed=packed, resident=(g.bres == "resident"),
-                             dims=dims, interpret=interpret)
+                             dims=dims, interpret=interpret, name=name)
         # fused reduction + epilogue inside the same program
         return _epilogue_f32(parts.sum(axis=0), bias, act, out_dtype)
     out = _tall_kinner(a, b, bias, bm=bm, bk=bk, act=act, packed=packed,
                        resident=(g.bres == "resident"),
                        revisit=(g.acc == "revisit"), dims=dims,
-                       m_split=m_split, interpret=interpret)
+                       m_split=m_split, interpret=interpret, name=name)
     if g.acc == "revisit":
         out = out.astype(out_dtype)   # the cast pass the model charges
     return out
@@ -488,8 +494,9 @@ def _tall_compute(a, b, bias, *, g, bm, bk, act, packed, impl, dims,
 
 @functools.partial(jax.jit,
                    static_argnames=("g", "bk", "bn", "act", "natural",
-                                    "impl", "dims"))
-def _skinny_compute(x, w, bias, *, g, bk, bn, act, natural, impl, dims):
+                                    "impl", "dims", "name"))
+def _skinny_compute(x, w, bias, *, g, bk, bn, act, natural, impl, dims,
+                    name=None):
     """Skinny twin of :func:`_tall_compute`; ``natural`` marks a
     packfuse point consuming the (K, N) weight layout directly."""
     m = x.shape[0]
@@ -532,12 +539,12 @@ def _skinny_compute(x, w, bias, *, g, bk, bn, act, natural, impl, dims):
         parts = _skinny_ksplit(x, w, bk=bk, bn=bn, splits=g.ksplit,
                                natural=natural,
                                resident=(g.bres == "resident"), dims=dims,
-                               interpret=interpret)
+                               interpret=interpret, name=name)
         return _epilogue_f32(parts.sum(axis=0), bias, act, out_dtype)
     out = _skinny_kinner(x, w, bias, bk=bk, bn=bn, act=act, natural=natural,
                          resident=(g.bres == "resident"),
                          revisit=(g.acc == "revisit"), dims=dims,
-                         interpret=interpret)
+                         interpret=interpret, name=name)
     if g.acc == "revisit":
         out = out.astype(out_dtype)
     return out
@@ -549,21 +556,24 @@ def _skinny_compute(x, w, bias, *, g, bk, bn, act, natural, impl, dims):
 
 
 def emit_tall_a(g: GenSpec, a, b, bias=None, act=None, *, bm: int = 0,
-                bk: int = 0, packed: bool = False, impl=None, schedule=None):
+                bk: int = 0, packed: bool = False, impl=None, schedule=None,
+                name=None):
     """Lower grammar point ``g`` for the tall-A orientation.
 
     Contract matches the PR-4 variant wrappers: returns (M, N) for
     natural inputs (padding sliced off) or (nm*bm, N) for packed inputs
-    (caller slices rows)."""
+    (caller slices rows).  ``name`` names the kernel's custom call in the
+    compiled program (None keeps Pallas's default)."""
     sch = schedule or DEFAULT_SCHEDULE
     if g == BASELINE_POINT:
         # the baseline point IS the PR-3 kernel: delegate so pre-grammar
         # measurement records keep timing identical jit programs
         if packed:
             return ops.tsmm_packed(a, b, bias, act=act, impl=impl,
-                                   dims=sch.dims, m_split=sch.m_split)
+                                   dims=sch.dims, m_split=sch.m_split,
+                                   name=name)
         return ops.tsmm(a, b, bias, bm=bm, bk=bk, act=act, impl=impl,
-                        dims=sch.dims, m_split=sch.m_split)
+                        dims=sch.dims, m_split=sch.m_split, name=name)
     impl = ops._resolve(impl)
     n = b.shape[1]
     if packed:
@@ -581,7 +591,7 @@ def emit_tall_a(g: GenSpec, a, b, bias=None, act=None, *, bm: int = 0,
     biasp = _pad_bias(bias, bp.shape[1])
     out = _tall_compute(ap, bp, biasp if fused else None, g=g, bm=bm, bk=bk,
                         act=act if fused else None, packed=packed, impl=impl,
-                        dims=sch.dims, m_split=sch.m_split)
+                        dims=sch.dims, m_split=sch.m_split, name=name)
     if not fused and (bias is not None or act not in (None, "none")):
         out = _split_epilogue(out, biasp, act)
     if packed:
@@ -591,27 +601,28 @@ def emit_tall_a(g: GenSpec, a, b, bias=None, act=None, *, bm: int = 0,
 
 def emit_skinny_a(g: GenSpec, x, w, bias=None, act=None, *, bk: int = 0,
                   bn: int = 0, packed: bool = True, impl=None,
-                  schedule=None):
+                  schedule=None, name=None):
     """Lower grammar point ``g`` for the skinny-A orientation.
 
     ``w`` is the packed (nk, nn, bk, bn) weight when ``packed`` else the
     natural (K, N) layout — non-packfuse points then OWN the per-call
     pack cost (eager, so the evaluator times it); packfuse points read
     the natural layout inside the kernel.  Returns (m, n_padded) — the
-    caller slices padded columns, as with ``ops.tsmm_skinny``."""
+    caller slices padded columns, as with ``ops.tsmm_skinny``.  ``name``
+    as in :func:`emit_tall_a`."""
     sch = schedule or DEFAULT_SCHEDULE
     if g.packfuse and packed:
         # weight already block-major (packed at load): nothing to fuse —
         # honest fallback to the baseline packed kernel
         return ops.tsmm_skinny(x, w, bias, act=act, impl=impl,
-                               dims=sch.dims)
+                               dims=sch.dims, name=name)
     if g == BASELINE_POINT:
         if not packed:
             # per-call pack — deliberately eager so the evaluator's timed
             # region pays it (prepack=False replay fidelity, DESIGN.md §9)
             w = packing.pack(w, bk, bn).blocks
         return ops.tsmm_skinny(x, w, bias, act=act, impl=impl,
-                               dims=sch.dims)
+                               dims=sch.dims, name=name)
     impl = ops._resolve(impl)
     m = x.shape[0]
     natural = bool(g.packfuse)
@@ -634,7 +645,7 @@ def emit_skinny_a(g: GenSpec, x, w, bias=None, act=None, *, bk: int = 0,
     biasp = _pad_bias(bias, np_)
     out = _skinny_compute(xp, wq, biasp if fused else None, g=g, bk=bk,
                           bn=bn, act=act if fused else None, natural=natural,
-                          impl=impl, dims=sch.dims)
+                          impl=impl, dims=sch.dims, name=name)
     if not fused and (bias is not None or act not in (None, "none")):
         out = _split_epilogue(out, biasp, act)
     return out[:m]

@@ -121,10 +121,10 @@ def _pad_bias(bias, npad: int):
 
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bk", "act", "impl", "dims",
-                                    "m_split"))
+                                    "m_split", "name"))
 def tsmm(a, b, bias=None, *, bm: int = 512, bk: int = 512,
          act: Optional[str] = None, impl: Optional[str] = None,
-         dims: tuple = (), m_split: int = 1):
+         dims: tuple = (), m_split: int = 1, name: Optional[str] = None):
     """Unpacked tall-A TSMM: C = act(A @ B + bias) (pads + slices
     internally).  The epilogue is fused into the kernel's final k step
     (DESIGN.md §11); ``dims``/``m_split`` are the plan's grid schedule."""
@@ -148,15 +148,16 @@ def tsmm(a, b, bias=None, *, bm: int = 512, bk: int = 512,
         return _ref.act_ref(out, act).astype(a.dtype)
     out = _k.tsmm_tall_a(ap_, bp_, _pad_bias(bias, npad), bm=bm_, bk=bk,
                          act=act, dims=dims, m_split=m_split,
-                         interpret=(impl == "pallas_interpret"))
+                         interpret=(impl == "pallas_interpret"), name=name)
     return out[:m, :n]
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("act", "impl", "dims", "m_split"))
+                   static_argnames=("act", "impl", "dims", "m_split",
+                                    "name"))
 def tsmm_packed(ap, b, bias=None, *, act: Optional[str] = None,
                 impl: Optional[str] = None, dims: tuple = (),
-                m_split: int = 1):
+                m_split: int = 1, name: Optional[str] = None):
     """Packed tall-A TSMM: C = act(unpack(Ap) @ B + bias).
     Ap (nm,nk,bm,bk); fused epilogue + grid schedule as in ``tsmm``."""
     impl = _resolve(impl)
@@ -170,13 +171,15 @@ def tsmm_packed(ap, b, bias=None, *, act: Optional[str] = None,
     else:
         out = _k.tsmm_packed_a(ap, bp_, biasp, act=act, dims=dims,
                                m_split=m_split,
-                               interpret=(impl == "pallas_interpret"))
+                               interpret=(impl == "pallas_interpret"),
+                               name=name)
     return out[:, :n]
 
 
-@functools.partial(jax.jit, static_argnames=("act", "impl", "dims"))
+@functools.partial(jax.jit, static_argnames=("act", "impl", "dims", "name"))
 def tsmm_skinny(x, wp, bias=None, *, act: Optional[str] = None,
-                impl: Optional[str] = None, dims: tuple = ()):
+                impl: Optional[str] = None, dims: tuple = (),
+                name: Optional[str] = None):
     """Skinny-A x packed-W with fused epilogue: act(X @ W + bias).
 
     X (m, K) — m is the skinny dim (decode batch); Wp (nk, nn, bk, bn).
@@ -192,5 +195,5 @@ def tsmm_skinny(x, wp, bias=None, *, act: Optional[str] = None,
     mp = _ceil_to(m, sublane(x.dtype))
     xp = pad2(x, mp, nk * bk)
     out = _k.tsmm_skinny_a(xp, wp, biasp, act=act, dims=dims,
-                           interpret=(impl == "pallas_interpret"))
+                           interpret=(impl == "pallas_interpret"), name=name)
     return out[:m, : (bias.shape[0] if bias is not None else n)]

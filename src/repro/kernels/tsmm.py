@@ -116,7 +116,8 @@ def _tall_grid(nm: int, nk: int, m_split: int):
 
 
 def tsmm_tall_a(a, b, bias=None, *, bm: int, bk: int, act=None,
-                interpret: bool = False, dims=(), m_split: int = 1):
+                interpret: bool = False, dims=(), m_split: int = 1,
+                name=None):
     """C = act(A @ B + bias).  A (M,K) with M % bm == 0, K % bk == 0;
     B (K,N), N is the full skinny dim kept resident per grid step (the
     paper: every worker holds the whole B block).  The epilogue is FUSED
@@ -157,6 +158,7 @@ def tsmm_tall_a(a, b, bias=None, *, bm: int, bk: int, act=None,
         scratch_shapes=[pltpu.VMEM((bm, n), jnp.float32)],
         compiler_params=_compiler_params(_semantics(dims, default)),
         interpret=interpret,
+        name=name,
     )(*args)
 
 
@@ -186,7 +188,7 @@ def _packed_a_kernel_nobias(a_ref, b_ref, o_ref, acc_ref, *, nk, k_axis, act):
 
 
 def tsmm_packed_a(ap, b, bias=None, *, act=None, interpret: bool = False,
-                  dims=(), m_split: int = 1):
+                  dims=(), m_split: int = 1, name=None):
     """C = act(unpack(Ap) @ B + bias) with Ap (nm, nk, bm, bk) block-major.
 
     Every A DMA is one contiguous (bm*bk)-element block — no strided HBM
@@ -227,6 +229,7 @@ def tsmm_packed_a(ap, b, bias=None, *, act=None, interpret: bool = False,
         scratch_shapes=[pltpu.VMEM((bm, n), jnp.float32)],
         compiler_params=_compiler_params(_semantics(dims, default)),
         interpret=interpret,
+        name=name,
     )(*args)
 
 
@@ -300,7 +303,7 @@ def _skinny_a_kernel_nobias(x_ref, w_ref, o_ref, acc_ref, *, nk, act):
 
 
 def tsmm_skinny_a(x, wp, bias=None, *, act=None, interpret: bool = False,
-                  dims=()):
+                  dims=(), name=None):
     """C = act(X @ unpack(Wp) + bias).
 
     X (m, K) with skinny m (decode batch); Wp (nk, nn, bk, bn) packed
@@ -332,4 +335,5 @@ def tsmm_skinny_a(x, wp, bias=None, *, act=None, interpret: bool = False,
         compiler_params=_compiler_params(
             _semantics(dims, ("parallel", "arbitrary"))),
         interpret=interpret,
+        name=name,
     )(*args)

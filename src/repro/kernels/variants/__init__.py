@@ -54,7 +54,8 @@ def applies_to(spec: KernelSpec, orientation: str) -> bool:
 
 
 def run_tall_a(spec: KernelSpec, a, b, bias=None, act=None, *, bm: int = 0,
-               bk: int = 0, packed: bool = False, impl=None, schedule=None):
+               bk: int = 0, packed: bool = False, impl=None, schedule=None,
+               name=None):
     """Dispatch a tall-A matmul to the generator at ``spec``'s grammar
     point.
 
@@ -64,7 +65,8 @@ def run_tall_a(spec: KernelSpec, a, b, bias=None, act=None, *, bm: int = 0,
     prefill path's act(A@B + bias) executes without a post-hoc (M, N)
     pass unless the point ASKS for one (``epi=split``), (DESIGN.md §11).
     ``schedule`` is the plan's ScheduleSpec (grid semantics / M
-    partitioning / multibuffer depth); None = default.
+    partitioning / multibuffer depth); None = default.  ``name`` names
+    the kernel in the compiled program (the leaf it serves).
     """
     if not applies_to(spec, "tall_a"):
         raise ValueError(f"kernel variant {spec.key()!r} has no tall_a "
@@ -72,20 +74,20 @@ def run_tall_a(spec: KernelSpec, a, b, bias=None, act=None, *, bm: int = 0,
     from repro.kernels import gen
     return gen.emit_tall_a(from_kernel_spec(spec), a, b, bias, act, bm=bm,
                            bk=bk, packed=packed, impl=impl,
-                           schedule=schedule)
+                           schedule=schedule, name=name)
 
 
 def run_skinny_a(spec: KernelSpec, x, w, bias=None, act=None, *,
                  bk: int = 0, bn: int = 0, packed: bool = True, impl=None,
-                 schedule=None):
+                 schedule=None, name=None):
     """Dispatch a skinny-A (decode) matmul to the generator at ``spec``'s
     grammar point.
 
     ``w`` is the packed (nk, nn, bk, bn) blocks when ``packed`` else the
     natural (K, N) weight.  A pack-fusing point against an
     already-packed weight falls back to the baseline kernel inside the
-    emitter (there is no pack left to fuse).  ``schedule`` as in
-    :func:`run_tall_a`.
+    emitter (there is no pack left to fuse).  ``schedule`` and ``name``
+    as in :func:`run_tall_a`.
     """
     if not applies_to(spec, "skinny_a"):
         raise ValueError(f"kernel variant {spec.key()!r} has no skinny_a "
@@ -93,7 +95,7 @@ def run_skinny_a(spec: KernelSpec, x, w, bias=None, act=None, *,
     from repro.kernels import gen
     return gen.emit_skinny_a(from_kernel_spec(spec), x, w, bias, act, bk=bk,
                              bn=bn, packed=packed, impl=impl,
-                             schedule=schedule)
+                             schedule=schedule, name=name)
 
 
 # ---------------------------------------------------------------------------

@@ -86,6 +86,7 @@ def _flash_per_shard(q, k, v, causal: bool):
                          check_vma=False)(q, k, v)
 
 
+@jax.named_scope("attention")
 def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
                       chunk: int = 512, q_offset=0, k_offset=None,
                       valid_from=None):
@@ -164,6 +165,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
     return outs.transpose(1, 0, 4, 2, 3, 5).reshape(b, sq, h, dv)
 
 
+@jax.named_scope("attention")
 def decode_attention(q, k_cache, v_cache, k_pos, cur_pos, *, window: int = 0,
                      valid_from=None):
     """One-step attention.  q: (B,1,H,D); caches: (B,S,KH,D);
@@ -216,9 +218,9 @@ def _qkv(p, cfg, x, kv_from=None):
     src = x if kv_from is None else kv_from
     sk = src.shape[1]
     h, kh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = linear(x, p["wq"], p.get("bq")).reshape(b, s, h, hd)
-    k = linear(src, p["wk"], p.get("bk")).reshape(b, sk, kh, hd)
-    v = linear(src, p["wv"], p.get("bv")).reshape(b, sk, kh, hd)
+    q = linear(x, p["wq"], p.get("bq"), name="wq").reshape(b, s, h, hd)
+    k = linear(src, p["wk"], p.get("bk"), name="wk").reshape(b, sk, kh, hd)
+    v = linear(src, p["wv"], p.get("bv"), name="wv").reshape(b, sk, kh, hd)
     return q, k, v
 
 
@@ -245,7 +247,7 @@ def gqa_forward(p, cfg, x, *, causal=True, pos_offset=0,
                             k_offset=0 if kv_from is not None else None,
                             valid_from=valid_from)
     out = out.reshape(b, s, cfg.num_heads * cfg.head_dim)
-    return linear(out, p["wo"]), (k, v)
+    return linear(out, p["wo"], name="wo"), (k, v)
 
 
 def gqa_decode(p, cfg, x, cache_k, cache_v, slot_pos, cur_pos, *,
@@ -262,13 +264,14 @@ def gqa_decode(p, cfg, x, cache_k, cache_v, slot_pos, cur_pos, *,
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
     slot = cur % cache_k.shape[1] if cfg.sliding_window else cur
-    cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, slot, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, slot, 0, 0))
-    slot_pos = jax.lax.dynamic_update_slice(slot_pos, cur[None], (slot,))
+    with jax.named_scope("cache_write"):
+        cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, slot, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, slot, 0, 0))
+        slot_pos = jax.lax.dynamic_update_slice(slot_pos, cur[None], (slot,))
     out = decode_attention(q, cache_k, cache_v, slot_pos, cur,
                            window=cfg.sliding_window, valid_from=valid_from)
     out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim)
-    return linear(out, p["wo"]), cache_k, cache_v, slot_pos
+    return linear(out, p["wo"], name="wo"), cache_k, cache_v, slot_pos
 
 
 def cross_decode(p, cfg, x, cross_k, cross_v):
@@ -276,10 +279,10 @@ def cross_decode(p, cfg, x, cross_k, cross_v):
     (computed ONCE per utterance — the pre-pack data-reuse story)."""
     b = x.shape[0]
     h, kh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = linear(x, p["wq"], p.get("bq")).reshape(b, 1, h, hd)
+    q = linear(x, p["wq"], p.get("bq"), name="wq").reshape(b, 1, h, hd)
     kpos = jnp.arange(cross_k.shape[1])
     out = decode_attention(q, cross_k, cross_v, kpos, cross_k.shape[1] - 1)
-    return linear(out.reshape(b, 1, h * hd), p["wo"])
+    return linear(out.reshape(b, 1, h * hd), p["wo"], name="wo")
 
 
 # ---------------------------------------------------------------------------
@@ -306,13 +309,14 @@ def _mla_qkv_train(p, cfg, x, pos):
     from repro.models.layers import rmsnorm
     b, s, _ = x.shape
     h, dn, dr, dv = cfg.num_heads, cfg.head_dim, cfg.rope_head_dim, cfg.v_head_dim
-    cq = rmsnorm(linear(x, p["wq_a"]), p["q_norm"], cfg.norm_eps)
-    q = linear(cq, p["wq_b"]).reshape(b, s, h, dn + dr)
+    cq = rmsnorm(linear(x, p["wq_a"], name="wq_a"), p["q_norm"],
+                 cfg.norm_eps)
+    q = linear(cq, p["wq_b"], name="wq_b").reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    ckv = linear(x, p["wkv_a"])
+    ckv = linear(x, p["wkv_a"], name="wkv_a")
     c_kv = rmsnorm(ckv[..., : cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
     k_rope = ckv[..., cfg.kv_lora_rank:][:, :, None, :]      # (B,S,1,dr)
-    kv = linear(c_kv, p["wkv_b"]).reshape(b, s, h, dn + dv)
+    kv = linear(c_kv, p["wkv_b"], name="wkv_b").reshape(b, s, h, dn + dv)
     k_nope, v = kv[..., :dn], kv[..., dn:]
     cos, sin = rope_tables(pos, dr, cfg.rope_theta)
     q_rope = apply_rope(q_rope, cos, sin)
@@ -333,7 +337,7 @@ def mla_forward(p, cfg, x, *, pos_offset=0, chunk: int = 512,
                             q_offset=pos_offset, valid_from=valid_from)
     # note: softmax scale uses full q dim (dn+dr) inside chunked_attention
     out = out.reshape(b, s, cfg.num_heads * cfg.v_head_dim)
-    return linear(out, p["wo"]), (c_kv, k_rope)
+    return linear(out, p["wo"], name="wo"), (c_kv, k_rope)
 
 
 def mla_decode(p, cfg, x, cache_c, cache_kr, cur_pos, *, valid_from=None):
@@ -347,13 +351,14 @@ def mla_decode(p, cfg, x, cache_c, cache_kr, cur_pos, *, valid_from=None):
     b = x.shape[0]
     h, dn, dr, dv, kvr = (cfg.num_heads, cfg.head_dim, cfg.rope_head_dim,
                           cfg.v_head_dim, cfg.kv_lora_rank)
-    cq = rmsnorm(linear(x, p["wq_a"]), p["q_norm"], cfg.norm_eps)
-    q = linear(cq, p["wq_b"]).reshape(b, h, dn + dr)
+    cq = rmsnorm(linear(x, p["wq_a"], name="wq_a"), p["q_norm"],
+                 cfg.norm_eps)
+    q = linear(cq, p["wq_b"], name="wq_b").reshape(b, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     cos, sin = rope_tables(jnp.asarray([cur_pos]), dr, cfg.rope_theta)
     q_rope = apply_rope(q_rope[:, None], cos, sin)[:, 0]     # (B,h,dr)
 
-    ckv = linear(x[:, 0], p["wkv_a"])
+    ckv = linear(x[:, 0], p["wkv_a"], name="wkv_a")
     c_new = rmsnorm(ckv[..., :kvr], p["kv_norm"], cfg.norm_eps)
     kr_new = ckv[..., kvr:]
     kr_new = apply_rope(kr_new[:, None, None], cos, sin)[:, 0, 0]
@@ -381,5 +386,5 @@ def mla_decode(p, cfg, x, cache_c, cache_kr, cur_pos, *, valid_from=None):
     pattn = jax.nn.softmax(s, axis=-1)
     o_c = jnp.einsum("bhs,bsc->bhc", pattn, cache_c.astype(jnp.float32))
     o = jnp.einsum("bhc,chv->bhv", o_c, w_uv).astype(x.dtype)
-    out = linear(o.reshape(b, 1, h * dv), p["wo"])
+    out = linear(o.reshape(b, 1, h * dv), p["wo"], name="wo")
     return out, cache_c, cache_kr

@@ -10,6 +10,7 @@ from repro.models.param import ParamTree
 from repro.sharding.context import shard_act
 
 
+@jax.named_scope("norm")
 def rmsnorm(x, scale, eps: float):
     dt = x.dtype
     x = x.astype(jnp.float32)
@@ -35,6 +36,7 @@ def silu(x):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("rope")
 def rope_tables(positions, dim: int, theta: float):
     """cos/sin tables for given integer positions (any shape)."""
     half = dim // 2
@@ -43,6 +45,7 @@ def rope_tables(positions, dim: int, theta: float):
     return jnp.cos(ang), jnp.sin(ang)
 
 
+@jax.named_scope("rope")
 def apply_rope(x, cos, sin):
     """x: (..., S, H, D); cos/sin: (S, D/2) or broadcastable (..., S, D/2)."""
     half = x.shape[-1] // 2
@@ -70,10 +73,12 @@ def init_swiglu(rng, d_model: int, d_ff: int, dtype, d_out: int = 0):
     return pt.build()
 
 
+@jax.named_scope("mlp")
 def swiglu(p, x):
-    h = linear(x, p["w_gate"], act="silu") * linear(x, p["w_up"])
+    h = (linear(x, p["w_gate"], act="silu", name="w_gate")
+         * linear(x, p["w_up"], name="w_up"))
     h = shard_act(h, "batch", "seq", "mlp")
-    return linear(h, p["w_down"])
+    return linear(h, p["w_down"], name="w_down")
 
 
 def init_gelu_mlp(rng, d_model: int, d_ff: int, dtype, d_out: int = 0):
@@ -97,9 +102,9 @@ def sinusoidal_pos(positions, dim: int):
 
 
 def gelu_mlp(p, x):
-    h = linear(x, p["w_in"], p["b_in"], act="gelu")
+    h = linear(x, p["w_in"], p["b_in"], act="gelu", name="w_in")
     h = shard_act(h, "batch", "seq", "mlp")
-    return linear(h, p["w_out"], p["b_out"])
+    return linear(h, p["w_out"], p["b_out"], name="w_out")
 
 
 # ---------------------------------------------------------------------------
@@ -115,6 +120,7 @@ def init_embed(rng, vocab: int, d_model: int, dtype, tie: bool):
     return pt.build()
 
 
+@jax.named_scope("embed")
 def embed_tokens(p, tokens):
     out = jnp.take(p["tok"], tokens, axis=0)
     return shard_act(out, "batch", "seq", "embed")
@@ -126,5 +132,5 @@ def unembed(p, x, tie: bool):
     # all-reduce in the backward pass — §Perf B4) and halve the logits
     # buffer (B x S x vocab is the largest activation in the program).
     w = p["tok"].T if tie else p["head"]
-    logits = linear(x, w)
+    logits = linear(x, w, name="head")
     return shard_act(logits, "batch", "seq", "vocab")

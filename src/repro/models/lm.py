@@ -113,6 +113,7 @@ def _kind(cfg) -> str:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("layers")
 def layer_stack(cfg, x, layer_params, step, extras=(), *, remat=None,
                 scan=None):
     """THE layer-stack entry point: every full-stack traversal (training /
@@ -130,6 +131,9 @@ def layer_stack(cfg, x, layer_params, step, extras=(), *, remat=None,
     scan = cfg.scan_layers if scan is None else scan
     xs = (layer_params,) + tuple(extras)
 
+    # the body's own ops under ``layer``; the scan's slicing of ``xs`` and
+    # stacking of outputs stay outside it
+    @jax.named_scope("layer")
     def body(xc, sl):
         return step(sl[0], xc, *sl[1:])
 
@@ -324,8 +328,9 @@ def lm_decode_step(params, cfg, cache, tokens):
     if kind != "ssm":
         slots = cache["slot_pos"].shape[0]
         slot = pos % slots if cfg.sliding_window else pos
-        slot_pos = jax.lax.dynamic_update_slice(
-            cache["slot_pos"], pos[None].astype(jnp.int32), (slot,))
+        with jax.named_scope("cache_write"):
+            slot_pos = jax.lax.dynamic_update_slice(
+                cache["slot_pos"], pos[None].astype(jnp.int32), (slot,))
         cache["slot_pos"] = slot_pos
         valid_from = cache.get("valid_from")
         for i in range(cfg.first_k_dense):
@@ -387,6 +392,15 @@ def lm_prefill_row(params, cfg, batch, cache, row, t_end):
     logits, _, (kvs, dense_kvs) = lm_forward(params, cfg, batch,
                                              collect_cache=True,
                                              pos_offset=t0)
+    return logits[:, -1:], _write_row(cfg, cache, kvs, dense_kvs, batch,
+                                      row, t0, lb)
+
+
+@jax.named_scope("cache_write")
+def _write_row(cfg, cache, kvs, dense_kvs, batch, row, t0, lb):
+    """``lm_prefill_row``'s cache write: the prompt's K/V into row ``row``
+    at slots ``[t0, t0 + lb)``, its ``valid_from`` and the occupied
+    slots."""
     cache = dict(cache)
     a, b_ = _cache_pair_names(cfg)
     ka, kb = kvs
@@ -413,4 +427,4 @@ def lm_prefill_row(params, cfg, batch, cache, row, t_end):
     sl = jnp.arange(cache["slot_pos"].shape[0], dtype=jnp.int32)
     cache["slot_pos"] = jnp.where((sl >= t0) & (sl < t0 + lb), sl,
                                   cache["slot_pos"]).astype(jnp.int32)
-    return logits[:, -1:], cache
+    return cache

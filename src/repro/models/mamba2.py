@@ -125,7 +125,7 @@ def mamba2_forward(p, cfg, x, *, h0=None, conv_init=None):
     Returns (out (B,S,d), (h_final, conv_tail)) for cache handoff."""
     b, s, _ = x.shape
     di, h, p_, n, g = _dims(cfg)
-    proj = linear(x, p["w_in"])
+    proj = linear(x, p["w_in"], name="w_in")
     z, xbc_raw, dt = _split_in(cfg, proj)
     if conv_init is not None:  # continue from cached conv tail (chunked prefill)
         full = jnp.concatenate([conv_init, xbc_raw], axis=1)
@@ -146,7 +146,7 @@ def mamba2_forward(p, cfg, x, *, h0=None, conv_init=None):
     y = y + p["d_skip"].astype(jnp.float32)[None, None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(b, s, di).astype(x.dtype)
     y = rmsnorm(y * silu(z), p["norm"], cfg.norm_eps)
-    out = linear(y, p["w_out"])
+    out = linear(y, p["w_out"], name="w_out")
     return out, (hfin, conv_tail)
 
 
@@ -155,7 +155,7 @@ def mamba2_decode(p, cfg, x, ssm_state, conv_cache, _cur_pos):
     conv_cache (B, conv-1, di+2GN) raw (pre-activation) inputs."""
     b = x.shape[0]
     di, h, p_, n, g = _dims(cfg)
-    proj = linear(x[:, 0], p["w_in"])                        # (B, ...)
+    proj = linear(x[:, 0], p["w_in"], name="w_in")           # (B, ...)
     z, xbc_new, dt = _split_in(cfg, proj[:, None, :])
     z, dt = z[:, 0], dt[:, 0]
     window = jnp.concatenate([conv_cache, xbc_new], axis=1)  # (B, conv, C)
@@ -176,7 +176,7 @@ def mamba2_decode(p, cfg, x, ssm_state, conv_cache, _cur_pos):
     y = y + p["d_skip"].astype(jnp.float32)[None, :, None] * xh
     y = y.reshape(b, di).astype(x.dtype)
     y = rmsnorm(y * silu(z), p["norm"], cfg.norm_eps)
-    out = linear(y[:, None], p["w_out"])
+    out = linear(y[:, None], p["w_out"], name="w_out")
     return out, ssm_state, conv_cache
 
 

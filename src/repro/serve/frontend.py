@@ -54,6 +54,8 @@ import math
 from collections import deque
 from typing import List, Optional
 
+from jax.profiler import TraceAnnotation as span
+
 from repro.resilience import failpoints
 from repro.serve.clock import StepCost
 from repro.serve.scheduler import ContinuousScheduler, Request, StreamResult
@@ -316,12 +318,13 @@ class AsyncEngine:
         self._deliver(emitted, finished)
 
     def _deliver(self, emitted, finished) -> None:
-        for st, tok, t in emitted:
-            if st["tag"] is not None:
-                st["tag"]._push(tok, t)
-        for tag, res in finished:
-            if tag is not None:
-                tag._finish(res, self.clock.now(), res.completed)
+        with span("serve.deliver"):
+            for st, tok, t in emitted:
+                if st["tag"] is not None:
+                    st["tag"]._push(tok, t)
+            for tag, res in finished:
+                if tag is not None:
+                    tag._finish(res, self.clock.now(), res.completed)
 
     def _reap(self) -> None:
         """Cancellation / deadline pass (§16), run at the top of every
@@ -380,16 +383,21 @@ class AsyncEngine:
     def _tick(self) -> None:
         """One scheduler iteration: budgeted admission, then — if a
         batch is live — either one lockstep decode step or, when the
-        cache clock is spent, truncation of every live stream."""
-        self._reap()
-        self._admit_phase()
-        if self.sched.active:
-            if self.sched.exhausted():
-                self._deliver([], self.sched.truncate())
-            else:
-                self._step_phase()
-        elif self._pending and not self.sched.can_admit():
-            self._drop_pending()
+        cache clock is spent, truncation of every live stream.  Host
+        spans (under an active profiler trace): ``serve.tick`` around
+        ``serve.reap``, the scheduler's ``serve.admit``/``serve.step``
+        and ``serve.deliver``."""
+        with span("serve.tick"):
+            with span("serve.reap"):
+                self._reap()
+            self._admit_phase()
+            if self.sched.active:
+                if self.sched.exhausted():
+                    self._deliver([], self.sched.truncate())
+                else:
+                    self._step_phase()
+            elif self._pending and not self.sched.can_admit():
+                self._drop_pending()
 
     # -- deterministic open-loop driver ---------------------------------
 

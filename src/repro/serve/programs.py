@@ -159,6 +159,25 @@ def tree_digest(tree) -> str:
     return h.hexdigest()
 
 
+def program_name(kind: str, bucket: int, tokens: int) -> str:
+    """The stable name a serving program is lowered under (its XLA module
+    is ``jit_<name>``), so a profile tells the programs apart by module
+    name alone: ``decode_step_b<slots>``, ``prefill_row_b<slots>_t<lb>``,
+    ``prefill_b<batch>_t<length>``."""
+    if kind == "decode":
+        return f"decode_step_b{bucket}"
+    return f"{kind}_b{bucket}_t{tokens}"
+
+
+def _named(fn, name: str):
+    """``fn`` under the function name ``name`` (what ``jax.jit`` names
+    the module after)."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 def aot_lower(fn, args, *, in_shardings=None, out_shardings=None,
               donate_argnums=()):
     """The ONE lowering helper: ``jit(fn).lower(*args)`` with optional
@@ -319,8 +338,10 @@ class ProgramStore:
             in_sh, out_sh = self.shardings_for(kind, args)
             from repro.core.linear import serving_ctx
             with serving_ctx(), sharding_ctx(self.lowering_mesh, self.opts):
+                fn = _named(self._fns[kind],
+                            program_name(kind, bucket, tokens))
                 lowered = aot_lower(
-                    self._fns[kind], structs, in_shardings=in_sh,
+                    fn, structs, in_shardings=in_sh,
                     out_shardings=out_sh, donate_argnums=DONATE[kind])
                 self._stats["lower_s"] += time.perf_counter() - t0
                 compiled = lowered.compile()

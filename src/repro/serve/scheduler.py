@@ -48,6 +48,7 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.serve.clock import StepCost, ensure_clock
 from repro.sharding.context import sharding_ctx
@@ -215,11 +216,6 @@ class SchedulerStats:
         queue report the same serving rate."""
         return self.generated_tokens / max(self.wall_s - self.compile_s, 1e-9)
 
-    @property
-    def wall_tokens_per_s(self) -> float:
-        """Raw throughput over the full wall clock, compile included."""
-        return self.generated_tokens / max(self.wall_s, 1e-9)
-
     def rows(self) -> list:
         out = [
             ("slots", self.slots),
@@ -385,20 +381,31 @@ class ContinuousScheduler:
         result).  ``arrival`` (clock seconds) stamps TTFT telemetry on
         the request's tier — the open-loop front end passes it, the
         closed-loop drain does not (arrival is meaningless there).
+
+        Host spans (recorded only under an active profiler trace):
+        ``serve.admit`` (args ``rid``, ``lb``, ``prompt``) around
+        ``serve.upload``, ``serve.dispatch``, ``serve.sample``,
+        ``serve.readback`` and ``serve.emit``, as in :meth:`step`.
         """
         assert self._opened and self.free, "no free slot"
-        eng, stats, clock = self.engine, self.stats, self.clock
         if toks is None or lb is None:
             toks, lb = self.prepare(req)
+        with span("serve.admit", rid=req.rid, lb=lb,
+                  prompt=int(toks.shape[0])):
+            return self._admit(req, toks, lb, tag, arrival)
+
+    def _admit(self, req, toks, lb, tag, arrival):
+        eng, stats, clock = self.engine, self.stats, self.clock
         row = self.free.pop()
         p = toks.shape[0]
         padded = np.zeros((lb,), np.int32)
         padded[lb - p:] = toks
-        batch = eng.place_batch(
-            {"tokens": jnp.asarray(padded)[None],
-             "pad": jnp.asarray([lb - p], jnp.int32)})
-        row_arg = eng.place_scalar(jnp.asarray(row, jnp.int32))
-        t_arg = eng.place_scalar(jnp.asarray(self.T, jnp.int32))
+        with span("serve.upload"):
+            batch = eng.place_batch(
+                {"tokens": jnp.asarray(padded)[None],
+                 "pad": jnp.asarray([lb - p], jnp.int32)})
+            row_arg = eng.place_scalar(jnp.asarray(row, jnp.int32))
+            t_arg = eng.place_scalar(jnp.asarray(self.T, jnp.int32))
         args = (eng.params, batch, self.cache, row_arg, t_arg)
         # first store acquire of this (slots, length-bucket) program:
         # attribute its AOT compile (or disk-load) time to compile_s, not
@@ -410,7 +417,8 @@ class ContinuousScheduler:
                                         bucket=self.slots, tokens=lb)
             self._progs[("prefill_row", lb)] = prog
             cold = prog.cold
-        logits, self.cache = prog.fn(*args)
+        with span("serve.dispatch"):
+            logits, self.cache = prog.fn(*args)
         if cold:
             jax.block_until_ready(logits)
             if clock.virtual:
@@ -418,37 +426,54 @@ class ContinuousScheduler:
             stats.compile_s += clock.now() - tc0
         if clock.virtual:
             clock.advance(self.step_cost.prefill_s(lb))
-        first = int(jnp.argmax(logits[0, -1]))
+        with span("serve.sample"):
+            first = jnp.argmax(logits[0, -1])
+        with span("serve.readback"):
+            first = int(first)
         t_tok = clock.now()
-        st = {"tag": tag, "req": req, "row": row, "lb": lb,
-              "prompt_len": int(p), "emitted": [first],
-              "admitted_at": self.T, "queue_steps": stats.steps}
-        self.active[row] = st
-        self.feed[row] = first
-        stats.admitted += 1
-        stats.prompt_tokens += int(p)
-        stats.prompt_pad_tokens += lb - p
-        stats.queue_steps_total += st["queue_steps"]
-        stats.generated_tokens += 1
-        tier = stats.tier(req.priority)
-        tier.admitted += 1
-        tier.queue_steps_total += st["queue_steps"]
-        tier.generated_tokens += 1
-        if arrival is not None:
-            tier.note_ttft(t_tok - arrival)
-        emitted = [(st, first, t_tok)]
-        finished = []
-        if self._finished(st):           # max_new_tokens == 1 / EOS
-            finished.append((tag, self._retire(st)))
-        return emitted, finished
+        with span("serve.emit"):
+            st = {"tag": tag, "req": req, "row": row, "lb": lb,
+                  "prompt_len": int(p), "emitted": [first],
+                  "admitted_at": self.T, "queue_steps": stats.steps}
+            self.active[row] = st
+            self.feed[row] = first
+            stats.admitted += 1
+            stats.prompt_tokens += int(p)
+            stats.prompt_pad_tokens += lb - p
+            stats.queue_steps_total += st["queue_steps"]
+            stats.generated_tokens += 1
+            tier = stats.tier(req.priority)
+            tier.admitted += 1
+            tier.queue_steps_total += st["queue_steps"]
+            tier.generated_tokens += 1
+            if arrival is not None:
+                tier.note_ttft(t_tok - arrival)
+            emitted = [(st, first, t_tok)]
+            finished = []
+            if self._finished(st):           # max_new_tokens == 1 / EOS
+                finished.append((tag, self._retire(st)))
+            return emitted, finished
 
     def step(self):
         """One lockstep decode step over the whole pool.
 
-        Returns ``(emitted, finished)`` event lists (see class doc)."""
+        Returns ``(emitted, finished)`` event lists (see class doc).
+
+        Host spans (recorded only under an active profiler trace):
+        ``serve.step`` (args ``step``, the steps before this one, and
+        ``live``, the live rows) around ``serve.upload`` (the fed
+        tokens), ``serve.dispatch`` (the decode program), ``serve.sample``
+        (the argmax), ``serve.readback`` (the host waits on the device)
+        and ``serve.emit`` (per-row emit and retire)."""
         assert self._opened and self.active, "no live streams to step"
+        with span("serve.step", step=self.stats.steps,
+                  live=len(self.active)):
+            return self._step()
+
+    def _step(self):
         eng, stats, clock = self.engine, self.stats, self.clock
-        tok = eng.place_tokens(jnp.asarray(self.feed[:, None]))
+        with span("serve.upload"):
+            tok = eng.place_tokens(jnp.asarray(self.feed[:, None]))
         tc0 = clock.now()
         prog, cold = self._progs.get("decode"), False
         if prog is None:
@@ -457,7 +482,8 @@ class ContinuousScheduler:
                                         bucket=self.slots, tokens=1)
             self._progs["decode"] = prog
             cold = prog.cold
-        logits, self.cache = prog.fn(eng.params, self.cache, tok)
+        with span("serve.dispatch"):
+            logits, self.cache = prog.fn(eng.params, self.cache, tok)
         if cold:
             jax.block_until_ready(logits)
             if clock.virtual:
@@ -469,17 +495,21 @@ class ContinuousScheduler:
         stats.steps += 1
         stats.slot_steps_active += len(self.active)
         t_tok = clock.now()
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
+        with span("serve.sample"):
+            nxt = jnp.argmax(logits[:, -1], axis=-1)
+        with span("serve.readback"):
+            nxt = np.asarray(nxt, np.int32)
         emitted, finished = [], []
-        for row in list(self.active):
-            st = self.active[row]
-            st["emitted"].append(int(nxt[row]))
-            self.feed[row] = nxt[row]
-            stats.generated_tokens += 1
-            stats.tier(st["req"].priority).generated_tokens += 1
-            emitted.append((st, int(nxt[row]), t_tok))
-            if self._finished(st):
-                finished.append((st["tag"], self._retire(st)))
+        with span("serve.emit"):
+            for row in list(self.active):
+                st = self.active[row]
+                st["emitted"].append(int(nxt[row]))
+                self.feed[row] = nxt[row]
+                stats.generated_tokens += 1
+                stats.tier(st["req"].priority).generated_tokens += 1
+                emitted.append((st, int(nxt[row]), t_tok))
+                if self._finished(st):
+                    finished.append((st["tag"], self._retire(st)))
         return emitted, finished
 
     def cancel(self, st):

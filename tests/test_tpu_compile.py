@@ -12,6 +12,8 @@ module is imported: only one process may load the TPU library at a time,
 and pytest-xdist workers import every test file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -107,3 +109,26 @@ def test_flash_attention_head_dim_128_compiles(one_chip):
     q = _struct((1, 20, 512, 128), jnp.bfloat16, one_chip)
     compiled = jax.jit(flash_attention).lower(q, q, q).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_planned_kernel_named_after_its_leaf(one_chip, monkeypatch):
+    """``linear(..., name=leaf)`` lowers the packed weight's kernel as a
+    ``tsmm_<leaf>`` custom call under the ``<leaf>`` scope, so the
+    profiler names the kernel by the weight it reads."""
+    from repro.core.linear import linear
+    from repro.core.packing import pack
+
+    monkeypatch.setenv("REPRO_TSMM_IMPL", "pallas")
+    plan = _best(Problem(16, D_FF, D_MODEL))
+    w = jax.ShapeDtypeStruct((D_FF, D_MODEL), jnp.bfloat16)
+    packed = jax.eval_shape(lambda w: pack(w, plan.bk, plan.bn), w)
+    packed = jax.tree.map(
+        lambda s: _struct(s.shape, s.dtype, one_chip), packed)
+    x = _struct((16, D_FF), jnp.bfloat16, one_chip)
+    hlo = jax.jit(lambda x, w: linear(x, w, name="w_down")).lower(
+        x, packed).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(re.search(r"%tsmm_w_down(\.\d+)? = ", line)
+                         for line in calls)
+    assert all("/w_down/" in line for line in calls)
