@@ -42,6 +42,11 @@ class PackedTensor:
     engine shards the block-count dims over ((None, None) off-mesh).  A
     Mosaic kernel cannot be partitioned by XLA, so ``tsmm_dot`` reads it
     to run one kernel per shard.
+
+    ``layer`` (an int32 scalar, usually traced) makes the tensor one
+    layer's view of layer-stacked ``blocks`` (L, n0, n1, b0, b1) without
+    slicing them: the skinny kernels read row ``layer`` themselves
+    (``kernels.tsmm.pallas_call``).  None for an ordinary tensor.
     """
 
     blocks: jnp.ndarray
@@ -49,14 +54,25 @@ class PackedTensor:
     orig_cols: int
     kernel_specs: tuple = ()
     shard_axes: tuple = (None, None)
+    layer: Optional[jnp.ndarray] = None
 
     def tree_flatten(self):
-        return (self.blocks,), (self.orig_rows, self.orig_cols,
-                                self.kernel_specs, self.shard_axes)
+        aux = (self.orig_rows, self.orig_cols, self.kernel_specs,
+               self.shard_axes)
+        if self.layer is None:
+            return (self.blocks,), aux
+        return (self.blocks, self.layer), aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(children[0], *aux)
+        return cls(children[0], *aux, *children[1:])
+
+    def at_layer(self, i) -> "PackedTensor":
+        """Layer ``i``'s view of a tensor stacked over one leading layer
+        dim (see ``layer``)."""
+        assert self.layer is None and self.blocks.ndim == 5, \
+            self.blocks.shape
+        return dataclasses.replace(self, layer=jnp.asarray(i, jnp.int32))
 
     # -- convenience ---------------------------------------------------
     @property
@@ -65,7 +81,8 @@ class PackedTensor:
 
     @property
     def lead_shape(self):
-        return self.blocks.shape[:-4]
+        return self.blocks.shape[:-4] if self.layer is None else \
+            self.blocks.shape[1:-4]
 
     @property
     def shape(self):
@@ -84,7 +101,8 @@ class PackedTensor:
         f = lambda bl: ops.unpack_blocks(bl, self.orig_rows, self.orig_cols)
         for _ in self.lead_shape:
             f = jax.vmap(f)
-        return f(self.blocks)
+        return f(self.blocks if self.layer is None
+                 else self.blocks[self.layer])
 
 
 def pack(w, b0: int, b1: int, alpha: float = 1.0) -> PackedTensor:

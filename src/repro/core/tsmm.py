@@ -110,25 +110,29 @@ def _mosaic_mesh(impl: Optional[str]):
 
 
 def _skinny_per_shard(mesh, b: PackedTensor, run, a2, bias, act):
-    """``run(x, blocks, bias, act)`` on each device's shard of the packed
-    weight ``b`` (its ``shard_axes``).  Column blocks sharded: each device
-    computes its own output columns, bias and activation fused.  Row
-    blocks sharded: each device contracts its k panel and the f32
+    """``run(x, blocks, bias, act, layer)`` on each device's shard of the
+    packed weight ``b`` (its ``shard_axes``; a layer view's stacked
+    blocks keep their layer dim whole).  Column blocks sharded: each
+    device computes its own output columns, bias and activation fused.
+    Row blocks sharded: each device contracts its k panel and the f32
     partials are summed across the axis before bias and activation.
     Returns (m, padded n)."""
     row_ax, col_ax = b.shard_axes
     nk, nn, bk, bn = b.blocks.shape[-4:]
     a2 = ops.pad2(a2, a2.shape[0], nk * bk)
-    args = [a2, b.blocks]
-    specs = [P(None, row_ax), P(row_ax, col_ax, None, None)]
+    view = b.layer is not None
+    args = [a2, b.blocks] + [b.layer] * view
+    specs = ([P(None, row_ax), P(*(None,) * view, row_ax, col_ax, None, None)]
+             + [P()] * view)
     if bias is not None and row_ax is None:
         args.append(ops._pad_bias(bias, nn * bn))
         specs.append(P(col_ax))
 
-    def local(x, w, *bias_):
+    def local(x, w, *rest):
+        layer, bias_ = (rest[0] if view else None), rest[view:]
         if row_ax is None:
-            return run(x, w, bias_[0] if bias_ else None, act)
-        part = run(x, w, None, None).astype(jnp.float32)
+            return run(x, w, bias_[0] if bias_ else None, act, layer)
+        part = run(x, w, None, None, layer).astype(jnp.float32)
         return jax.lax.psum(part, row_ax)
 
     out = jax.shard_map(local, mesh=mesh, in_specs=tuple(specs),
@@ -250,15 +254,15 @@ def tsmm_dot(a, b, *, bias=None, act: Optional[str] = None,
         sched = sched_override or sched
 
         def _packed(use_impl):
-            def run(x, w, bias_, act_):
+            def run(x, w, bias_, act_, layer):
                 return variants.run_skinny_a(
                     spec, x, w, bias_, act_, bk=bk, bn=bn, packed=True,
-                    impl=use_impl, schedule=sched, name=name)
+                    impl=use_impl, schedule=sched, name=name, layer=layer)
             mesh = _mosaic_mesh(use_impl)
             if mesh is not None:
                 out = _skinny_per_shard(mesh, b, run, a2, bias, act)
             else:
-                out = run(a2, b.blocks, bias, act)
+                out = run(a2, b.blocks, bias, act, b.layer)
             return out[:, : b.orig_cols]
 
         out = _laddered(

@@ -312,12 +312,13 @@ def _tall_kouter(a, b, *, bm, bk, packed, dims, interpret, name=None):
 
 
 def _skinny_kinner(x, w, bias, *, bk, bn, act, natural, resident, revisit,
-                   dims, interpret, name=None):
+                   dims, interpret, name=None, layer=None):
     """K-innermost skinny-A program.  ``natural`` reads W in its (K, N)
     layout with a strided index map (the packfuse axis — no per-call pack
     pass); ``resident`` pins the whole X row panel (constant map) and
     ``pl.ds``-slices its k panel; ``revisit`` accumulates into the fp32
-    output block instead of VMEM scratch (caller casts)."""
+    output block instead of VMEM scratch (caller casts).  ``layer``: W is
+    layer-stacked (``tsmm.pallas_call``)."""
     m, k = x.shape
     if natural:
         kw, n = w.shape
@@ -325,7 +326,7 @@ def _skinny_kinner(x, w, bias, *, bk, bn, act, natural, resident, revisit,
                                                           bk, bn)
         nk, nn = kw // bk, n // bn
     else:
-        nk, nn, bk, bn = w.shape
+        nk, nn, bk, bn = w.shape[-4:]
         assert k == nk * bk, (x.shape, w.shape)
         n = nn * bn
     x_spec = (pl.BlockSpec((m, k), lambda i, j: (0, 0)) if resident
@@ -360,7 +361,7 @@ def _skinny_kinner(x, w, bias, *, bk, bn, act, natural, resident, revisit,
             o_ref[...] = _k._epilogue(acc_ref[...], bias_ref,
                                       act).astype(o_ref.dtype)
 
-    return pl.pallas_call(
+    return _k.pallas_call(
         kernel,
         grid=(nn, nk),
         in_specs=in_specs,
@@ -373,22 +374,23 @@ def _skinny_kinner(x, w, bias, *, bk, bn, act, natural, resident, revisit,
             _k._semantics(dims, ("parallel", "arbitrary"))),
         interpret=interpret,
         name=name,
+        layer=layer,
     )(*args)
 
 
 def _skinny_ksplit(x, w, *, bk, bn, splits, natural, resident, dims,
-                   interpret, name=None):
+                   interpret, name=None, layer=None):
     """K-split skinny-A: fp32 partials out (splits, m, N); caller reduces
     + applies the epilogue.  ``natural`` strides the (K, N) weight
     directly; ``resident`` pins the whole X and slices the group-local k
-    panel."""
+    panel; ``layer`` as in :func:`_skinny_kinner`."""
     m, k = x.shape
     if natural:
         kw, nw = w.shape
         assert kw % bk == 0 and nw % bn == 0, (w.shape, bk, bn)
         nk, nn = kw // bk, nw // bn
     else:
-        nk, nn, bk, bn = w.shape
+        nk, nn, bk, bn = w.shape[-4:]
     assert k == nk * bk, (x.shape, w.shape)
     n = nn * bn
     assert nk % splits == 0, (nk, splits)
@@ -418,7 +420,7 @@ def _skinny_ksplit(x, w, *, bk, bn, splits, natural, resident, dims,
         def _done():
             o_ref[0] = acc_ref[...]
 
-    return pl.pallas_call(
+    return _k.pallas_call(
         kernel,
         grid=(nn, splits, nki),
         in_specs=[x_spec, w_spec],
@@ -429,6 +431,7 @@ def _skinny_ksplit(x, w, *, bk, bn, splits, natural, resident, dims,
             _k._semantics(dims, ("parallel", "parallel", "arbitrary"))),
         interpret=interpret,
         name=name,
+        layer=layer,
     )(x, w)
 
 
@@ -495,10 +498,11 @@ def _tall_compute(a, b, bias, *, g, bm, bk, act, packed, impl, dims,
 @functools.partial(jax.jit,
                    static_argnames=("g", "bk", "bn", "act", "natural",
                                     "impl", "dims", "name"))
-def _skinny_compute(x, w, bias, *, g, bk, bn, act, natural, impl, dims,
-                    name=None):
+def _skinny_compute(x, w, bias, layer=None, *, g, bk, bn, act, natural,
+                    impl, dims, name=None):
     """Skinny twin of :func:`_tall_compute`; ``natural`` marks a
-    packfuse point consuming the (K, N) weight layout directly."""
+    packfuse point consuming the (K, N) weight layout directly;
+    ``layer`` marks a layer-stacked packed W (``tsmm.pallas_call``)."""
     m = x.shape[0]
     out_dtype = x.dtype
     if natural:
@@ -506,9 +510,11 @@ def _skinny_compute(x, w, bias, *, g, bk, bn, act, natural, impl, dims,
         nk = w.shape[0] // bk
         nn = n // bn
     else:
-        nk, nn = w.shape[0], w.shape[1]
+        nk, nn = w.shape[-4], w.shape[-3]
         n = nn * bn
     if impl == "xla":
+        if layer is not None:
+            w, layer = w[layer], None
         if g.ksplit > 1:
             if natural:
                 kk = w.shape[0] // g.ksplit
@@ -539,12 +545,12 @@ def _skinny_compute(x, w, bias, *, g, bk, bn, act, natural, impl, dims,
         parts = _skinny_ksplit(x, w, bk=bk, bn=bn, splits=g.ksplit,
                                natural=natural,
                                resident=(g.bres == "resident"), dims=dims,
-                               interpret=interpret, name=name)
+                               interpret=interpret, name=name, layer=layer)
         return _epilogue_f32(parts.sum(axis=0), bias, act, out_dtype)
     out = _skinny_kinner(x, w, bias, bk=bk, bn=bn, act=act, natural=natural,
                          resident=(g.bres == "resident"),
                          revisit=(g.acc == "revisit"), dims=dims,
-                         interpret=interpret, name=name)
+                         interpret=interpret, name=name, layer=layer)
     if g.acc == "revisit":
         out = out.astype(out_dtype)
     return out
@@ -601,28 +607,30 @@ def emit_tall_a(g: GenSpec, a, b, bias=None, act=None, *, bm: int = 0,
 
 def emit_skinny_a(g: GenSpec, x, w, bias=None, act=None, *, bk: int = 0,
                   bn: int = 0, packed: bool = True, impl=None,
-                  schedule=None, name=None):
+                  schedule=None, name=None, layer=None):
     """Lower grammar point ``g`` for the skinny-A orientation.
 
     ``w`` is the packed (nk, nn, bk, bn) weight when ``packed`` else the
     natural (K, N) layout — non-packfuse points then OWN the per-call
     pack cost (eager, so the evaluator times it); packfuse points read
-    the natural layout inside the kernel.  Returns (m, n_padded) — the
-    caller slices padded columns, as with ``ops.tsmm_skinny``.  ``name``
-    as in :func:`emit_tall_a`."""
+    the natural layout inside the kernel.  A packed ``w`` may be the
+    layer-stacked (L, nk, nn, bk, bn), read at row ``layer``.  Returns
+    (m, n_padded) — the caller slices padded columns, as with
+    ``ops.tsmm_skinny``.  ``name`` as in :func:`emit_tall_a`."""
     sch = schedule or DEFAULT_SCHEDULE
+    assert layer is None or packed, "a layer index reads packed weights"
     if g.packfuse and packed:
         # weight already block-major (packed at load): nothing to fuse —
         # honest fallback to the baseline packed kernel
         return ops.tsmm_skinny(x, w, bias, act=act, impl=impl,
-                               dims=sch.dims, name=name)
+                               dims=sch.dims, name=name, layer=layer)
     if g == BASELINE_POINT:
         if not packed:
             # per-call pack — deliberately eager so the evaluator's timed
             # region pays it (prepack=False replay fidelity, DESIGN.md §9)
             w = packing.pack(w, bk, bn).blocks
         return ops.tsmm_skinny(x, w, bias, act=act, impl=impl,
-                               dims=sch.dims, name=name)
+                               dims=sch.dims, name=name, layer=layer)
     impl = ops._resolve(impl)
     m = x.shape[0]
     natural = bool(g.packfuse)
@@ -634,7 +642,7 @@ def emit_skinny_a(g: GenSpec, x, w, bias=None, act=None, *, bk: int = 0,
     else:
         if not packed:
             w = packing.pack(w, bk, bn).blocks   # eager: timed per call
-        nk, nn, bk, bn = w.shape
+        nk, nn, bk, bn = w.shape[-4:]
         wq, kp, np_ = w, nk * bk, nn * bn
     xp = ops.pad2(x, _ceil_to(m, ops.sublane(x.dtype)), kp)
     if g.ksplit > 1:
@@ -643,9 +651,10 @@ def emit_skinny_a(g: GenSpec, x, w, bias=None, act=None, *, bk: int = 0,
             g = dataclasses.replace(g, ksplit=s)
     fused = g.epi != "split"
     biasp = _pad_bias(bias, np_)
-    out = _skinny_compute(xp, wq, biasp if fused else None, g=g, bk=bk,
-                          bn=bn, act=act if fused else None, natural=natural,
-                          impl=impl, dims=sch.dims, name=name)
+    out = _skinny_compute(xp, wq, biasp if fused else None, layer, g=g,
+                          bk=bk, bn=bn, act=act if fused else None,
+                          natural=natural, impl=impl, dims=sch.dims,
+                          name=name)
     if not fused and (bias is not None or act not in (None, "none")):
         out = _split_epilogue(out, biasp, act)
     return out[:m]

@@ -179,21 +179,25 @@ def tsmm_packed(ap, b, bias=None, *, act: Optional[str] = None,
 @functools.partial(jax.jit, static_argnames=("act", "impl", "dims", "name"))
 def tsmm_skinny(x, wp, bias=None, *, act: Optional[str] = None,
                 impl: Optional[str] = None, dims: tuple = (),
-                name: Optional[str] = None):
+                name: Optional[str] = None, layer=None):
     """Skinny-A x packed-W with fused epilogue: act(X @ W + bias).
 
-    X (m, K) — m is the skinny dim (decode batch); Wp (nk, nn, bk, bn).
+    X (m, K) — m is the skinny dim (decode batch); Wp (nk, nn, bk, bn),
+    or the layer-stacked (L, nk, nn, bk, bn) read at row ``layer``.
     """
     impl = _resolve(impl)
     m, k = x.shape
-    nk, nn, bk, bn = wp.shape
+    nk, nn, bk, bn = wp.shape[-4:]
     n = nn * bn
     biasp = None if bias is None else jnp.pad(bias, (0, n - bias.shape[0]))
     if impl == "xla":
+        if layer is not None:
+            wp = wp[layer]
         out = _xla_skinny_a(pad2(x, m, nk * bk), wp, biasp, act)
         return out[:, : (bias.shape[0] if bias is not None else n)]
     mp = _ceil_to(m, sublane(x.dtype))
     xp = pad2(x, mp, nk * bk)
     out = _k.tsmm_skinny_a(xp, wp, biasp, act=act, dims=dims,
-                           interpret=(impl == "pallas_interpret"), name=name)
+                           interpret=(impl == "pallas_interpret"), name=name,
+                           layer=layer)
     return out[:m, : (bias.shape[0] if bias is not None else n)]

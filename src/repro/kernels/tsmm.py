@@ -41,6 +41,36 @@ def _compiler_params(dimension_semantics):
                                 vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
+def pallas_call(kernel, *, grid, in_specs, out_specs, scratch_shapes=(),
+                layer=None, **kw):
+    """``pl.pallas_call`` of a skinny-A kernel whose operand 1 is a packed
+    weight.  With ``layer`` (an int32 scalar) that operand is the whole
+    layer-stacked array (L, nk, nn, bk, bn) and the kernel's own DMAs read
+    its blocks from row ``layer``: no per-layer slice of the weight is
+    made ahead of the kernel, so its HBM read stays inside the kernel."""
+    if layer is None:
+        return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
+                              out_specs=out_specs,
+                              scratch_shapes=scratch_shapes, **kw)
+
+    def shift(spec, stacked=False):
+        # scalar-prefetch index maps take the scalar refs last
+        f = spec.index_map
+        if stacked:
+            return pl.BlockSpec((None, *spec.block_shape),
+                                lambda *g: (g[-1][0], *f(*g[:-1])))
+        return pl.BlockSpec(spec.block_shape, lambda *g: f(*g[:-1]))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=grid,
+        in_specs=[shift(s, i == 1) for i, s in enumerate(in_specs)],
+        out_specs=shift(out_specs), scratch_shapes=scratch_shapes)
+    call = pl.pallas_call(lambda layer_ref, *refs: kernel(*refs),
+                          grid_spec=grid_spec, **kw)
+    return lambda *args: call(
+        jnp.reshape(layer, (1,)).astype(jnp.int32), *args)
+
+
 def _semantics(dims, default: tuple) -> tuple:
     """Grid dimension semantics: the schedule's override when it matches
     the grid rank, else the kernel's default (a rank mismatch can only
@@ -303,14 +333,15 @@ def _skinny_a_kernel_nobias(x_ref, w_ref, o_ref, acc_ref, *, nk, act):
 
 
 def tsmm_skinny_a(x, wp, bias=None, *, act=None, interpret: bool = False,
-                  dims=(), name=None):
+                  dims=(), name=None, layer=None):
     """C = act(X @ unpack(Wp) + bias).
 
     X (m, K) with skinny m (decode batch); Wp (nk, nn, bk, bn) packed
-    weights.  The whole X row-panel stays VMEM-resident across the grid
-    (paper: the skinny operand is never split)."""
+    weights, or (L, nk, nn, bk, bn) with ``layer`` (see
+    :func:`pallas_call`).  The whole X row-panel stays VMEM-resident
+    across the grid (paper: the skinny operand is never split)."""
     m, k = x.shape
-    nk, nn, bk, bn = wp.shape
+    nk, nn, bk, bn = wp.shape[-4:]
     assert k == nk * bk, (x.shape, wp.shape)
     n = nn * bn
     in_specs = [
@@ -325,7 +356,7 @@ def tsmm_skinny_a(x, wp, bias=None, *, act=None, interpret: bool = False,
         kernel = functools.partial(_skinny_a_kernel, nk=nk, act=act)
     else:
         kernel = functools.partial(_skinny_a_kernel_nobias, nk=nk, act=act)
-    return pl.pallas_call(
+    return pallas_call(
         kernel,
         grid=(nn, nk),
         in_specs=in_specs,
@@ -336,4 +367,5 @@ def tsmm_skinny_a(x, wp, bias=None, *, act=None, interpret: bool = False,
             _semantics(dims, ("parallel", "arbitrary"))),
         interpret=interpret,
         name=name,
+        layer=layer,
     )(*args)

@@ -79,7 +79,7 @@ def run_tall_a(spec: KernelSpec, a, b, bias=None, act=None, *, bm: int = 0,
 
 def run_skinny_a(spec: KernelSpec, x, w, bias=None, act=None, *,
                  bk: int = 0, bn: int = 0, packed: bool = True, impl=None,
-                 schedule=None, name=None):
+                 schedule=None, name=None, layer=None):
     """Dispatch a skinny-A (decode) matmul to the generator at ``spec``'s
     grammar point.
 
@@ -87,7 +87,8 @@ def run_skinny_a(spec: KernelSpec, x, w, bias=None, act=None, *,
     natural (K, N) weight.  A pack-fusing point against an
     already-packed weight falls back to the baseline kernel inside the
     emitter (there is no pack left to fuse).  ``schedule`` and ``name``
-    as in :func:`run_tall_a`.
+    as in :func:`run_tall_a`; ``layer`` reads row ``layer`` of a
+    layer-stacked packed ``w`` (L, nk, nn, bk, bn) inside the kernel.
     """
     if not applies_to(spec, "skinny_a"):
         raise ValueError(f"kernel variant {spec.key()!r} has no skinny_a "
@@ -95,7 +96,7 @@ def run_skinny_a(spec: KernelSpec, x, w, bias=None, act=None, *,
     from repro.kernels import gen
     return gen.emit_skinny_a(from_kernel_spec(spec), x, w, bias, act, bk=bk,
                              bn=bn, packed=packed, impl=impl,
-                             schedule=schedule, name=name)
+                             schedule=schedule, name=name, layer=layer)
 
 
 # ---------------------------------------------------------------------------

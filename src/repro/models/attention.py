@@ -167,13 +167,18 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 @jax.named_scope("attention")
 def decode_attention(q, k_cache, v_cache, k_pos, cur_pos, *, window: int = 0,
-                     valid_from=None):
+                     valid_from=None, k_new=None, v_new=None):
     """One-step attention.  q: (B,1,H,D); caches: (B,S,KH,D);
     k_pos: (S,) absolute positions held by each cache slot (-1 = empty);
     valid_from: optional (B,) per-row first-valid position — slots before
-    it belong to left-padding or a previous (recycled) stream."""
+    it belong to left-padding or a previous (recycled) stream.
+
+    ``k_new``/``v_new`` (B,1,KH,D): the step's own key and value, not in
+    the cache.  Their score joins the cached slots' in the one softmax,
+    which equals writing them into their slot and attending over the
+    cache; ``k_pos`` must then mark that slot empty."""
     b, _, h, d = q.shape
-    kh = k_cache.shape[2]
+    sk, kh = k_cache.shape[1], k_cache.shape[2]
     g = h // kh
     qg = q.reshape(b, kh, g, d)
     s = jnp.einsum("bhgd,bkhd->bhgk", qg, k_cache,
@@ -187,9 +192,15 @@ def decode_attention(q, k_cache, v_cache, k_pos, cur_pos, *, window: int = 0,
                       s, NEG_INF)
     else:
         s = jnp.where(valid[None, None, None], s, NEG_INF)
+    if k_new is not None:
+        s_new = jnp.einsum("bhgd,bhd->bhg", qg, k_new[:, 0],
+                           preferred_element_type=jnp.float32) * d ** -0.5
+        s = jnp.concatenate([s, s_new[..., None]], axis=-1)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgk,bkhd->bhgd", p, v_cache,
+    out = jnp.einsum("bhgk,bkhd->bhgd", p[..., :sk], v_cache,
                      preferred_element_type=jnp.float32)
+    if v_new is not None:
+        out = out + p[..., sk:] * v_new[:, 0, :, None, :]
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
@@ -250,12 +261,25 @@ def gqa_forward(p, cfg, x, *, causal=True, pos_offset=0,
     return linear(out, p["wo"], name="wo"), (k, v)
 
 
-def gqa_decode(p, cfg, x, cache_k, cache_v, slot_pos, cur_pos, *,
+def cache_slot(cfg, slots: int, pos):
+    """The cache slot the token at ``pos`` is written to: ``pos % slots``
+    in a sliding-window ring, else ``pos``.  A decode step masks this slot
+    (:func:`gqa_decode`, :func:`mla_decode`) and its caller writes the
+    token's entries there, both from this one expression."""
+    pos = jnp.asarray(pos, jnp.int32)
+    return pos % slots if cfg.sliding_window else pos
+
+
+def gqa_decode(p, cfg, x, cache_k, cache_v, slot_pos, cur_pos, slot, *,
                use_rope: bool = True, valid_from=None):
-    """One token.  x: (B,1,d).  Caches (B,S,KH,D); slot_pos (S,) absolute
-    positions per slot.  Batch is position-aligned (continuous batching
-    with aligned steps — see serve/engine.py); ``valid_from`` (B,) masks
-    each row's cache below its own admission boundary."""
+    """One token.  x: (B,1,d).  Caches (B,S,KH,D), read only; slot_pos (S,)
+    absolute positions per slot.  Batch is position-aligned (continuous
+    batching with aligned steps — see serve/engine.py); ``valid_from`` (B,)
+    masks each row's cache below its own admission boundary.
+
+    Returns ``(out, k, v)``: the token's K/V (B,1,KH,D) are attended as
+    they are and left for the caller to write into ``slot``
+    (:func:`cache_slot`), whose old occupant is masked."""
     b = x.shape[0]
     q, k, v = _qkv(p, cfg, x)
     cur = jnp.asarray(cur_pos, jnp.int32)
@@ -263,15 +287,12 @@ def gqa_decode(p, cfg, x, cache_k, cache_v, slot_pos, cur_pos, *,
         cos, sin = rope_tables(cur[None], cfg.head_dim, cfg.rope_theta)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-    slot = cur % cache_k.shape[1] if cfg.sliding_window else cur
-    with jax.named_scope("cache_write"):
-        cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, slot, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, slot, 0, 0))
-        slot_pos = jax.lax.dynamic_update_slice(slot_pos, cur[None], (slot,))
-    out = decode_attention(q, cache_k, cache_v, slot_pos, cur,
-                           window=cfg.sliding_window, valid_from=valid_from)
+    k_pos = jnp.where(jnp.arange(slot_pos.shape[0]) == slot, -1, slot_pos)
+    out = decode_attention(q, cache_k, cache_v, k_pos, cur,
+                           window=cfg.sliding_window, valid_from=valid_from,
+                           k_new=k, v_new=v)
     out = out.reshape(b, 1, cfg.num_heads * cfg.head_dim)
-    return linear(out, p["wo"], name="wo"), cache_k, cache_v, slot_pos
+    return linear(out, p["wo"], name="wo"), k, v
 
 
 def cross_decode(p, cfg, x, cross_k, cross_v):
@@ -340,12 +361,17 @@ def mla_forward(p, cfg, x, *, pos_offset=0, chunk: int = 512,
     return linear(out, p["wo"], name="wo"), (c_kv, k_rope)
 
 
-def mla_decode(p, cfg, x, cache_c, cache_kr, cur_pos, *, valid_from=None):
-    """Absorbed-matrix decode over the compressed cache.
+def mla_decode(p, cfg, x, cache_c, cache_kr, cur_pos, slot, *,
+               valid_from=None):
+    """Absorbed-matrix decode over the compressed cache, read only.
 
     cache_c: (B,S,kvr)  cache_kr: (B,S,dr).  The q_nope->c-space and
     c->v absorbtions avoid materializing per-head K/V for 32k positions —
     and both absorbed GEMMs are TSMM-shaped (B x kvr against wide heads).
+
+    Returns ``(out, c, kr)``: the token's compressed entries (B,1,kvr) and
+    (B,1,dr) are attended as they are and left for the caller to write
+    into ``slot`` (:func:`cache_slot`), whose old occupant is masked.
     """
     from repro.models.layers import rmsnorm
     b = x.shape[0]
@@ -362,8 +388,6 @@ def mla_decode(p, cfg, x, cache_c, cache_kr, cur_pos, *, valid_from=None):
     c_new = rmsnorm(ckv[..., :kvr], p["kv_norm"], cfg.norm_eps)
     kr_new = ckv[..., kvr:]
     kr_new = apply_rope(kr_new[:, None, None], cos, sin)[:, 0, 0]
-    cache_c = jax.lax.dynamic_update_slice(cache_c, c_new[:, None], (0, cur_pos, 0))
-    cache_kr = jax.lax.dynamic_update_slice(cache_kr, kr_new[:, None], (0, cur_pos, 0))
 
     wkv_b = p["wkv_b"]
     w = wkv_b.unpack() if hasattr(wkv_b, "unpack") else wkv_b
@@ -371,20 +395,27 @@ def mla_decode(p, cfg, x, cache_c, cache_kr, cur_pos, *, valid_from=None):
     w_uk, w_uv = w[..., :dn], w[..., dn:]
     q_c = jnp.einsum("bhd,chd->bhc", q_nope, w_uk,
                      preferred_element_type=jnp.float32)     # absorb into c-space
+    q_rope = q_rope.astype(jnp.float32)
+    scale = (dn + dr) ** -0.5
     s = (jnp.einsum("bhc,bsc->bhs", q_c, cache_c.astype(jnp.float32))
-         + jnp.einsum("bhr,bsr->bhs", q_rope.astype(jnp.float32),
-                      cache_kr.astype(jnp.float32)))
-    s = s * (dn + dr) ** -0.5
+         + jnp.einsum("bhr,bsr->bhs", q_rope, cache_kr.astype(jnp.float32)))
+    s = s * scale
     pos_s = jnp.arange(cache_c.shape[1])
-    valid = pos_s <= cur_pos
+    valid = (pos_s <= cur_pos) & (pos_s != slot)
     if valid_from is not None:
         s = jnp.where((valid[None, :] &
                        (pos_s[None, :] >= valid_from[:, None]))[:, None],
                       s, NEG_INF)
     else:
         s = jnp.where(valid[None, None], s, NEG_INF)
+    c_f = c_new.astype(jnp.float32)
+    s_new = (jnp.einsum("bhc,bc->bh", q_c, c_f)
+             + jnp.einsum("bhr,br->bh", q_rope, kr_new.astype(jnp.float32)))
+    s = jnp.concatenate([s, (s_new * scale)[..., None]], axis=-1)
     pattn = jax.nn.softmax(s, axis=-1)
-    o_c = jnp.einsum("bhs,bsc->bhc", pattn, cache_c.astype(jnp.float32))
+    o_c = (jnp.einsum("bhs,bsc->bhc", pattn[..., :-1],
+                      cache_c.astype(jnp.float32))
+           + pattn[..., -1:] * c_f[:, None])
     o = jnp.einsum("bhc,chv->bhv", o_c, w_uv).astype(x.dtype)
     out = linear(o.reshape(b, 1, h * dv), p["wo"], name="wo")
-    return out, cache_c, cache_kr
+    return out, c_new[:, None], kr_new[:, None]

@@ -163,15 +163,18 @@ def encdec_decode_step(params, cfg, cache, tokens):
     x = embed_tokens(params["embed"], tokens)
     x = x + sinusoidal_pos(pos[None], cfg.d_model)[None].astype(x.dtype)
     cache = dict(cache)
+    slot = A.cache_slot(cfg, cache["slot_pos"].shape[0], pos)
     slot_pos = jax.lax.dynamic_update_slice(
-        cache["slot_pos"], pos[None].astype(jnp.int32), (pos,))
+        cache["slot_pos"], pos[None].astype(jnp.int32), (slot,))
     cache["slot_pos"] = slot_pos
 
     def body(xc, lin):
         lp, lk, lv, lck, lcv = lin
-        h, nk, nv, _ = A.gqa_decode(lp["self_attn"], cfg,
-                                    _apply_ln(lp, "ln1", xc, cfg.norm_eps),
-                                    lk, lv, slot_pos, pos, use_rope=False)
+        h, k, v = A.gqa_decode(lp["self_attn"], cfg,
+                               _apply_ln(lp, "ln1", xc, cfg.norm_eps),
+                               lk, lv, slot_pos, pos, slot, use_rope=False)
+        nk = jax.lax.dynamic_update_slice(lk, k, (0, slot, 0, 0))
+        nv = jax.lax.dynamic_update_slice(lv, v, (0, slot, 0, 0))
         xc = xc + h
         h = A.cross_decode(lp["cross_attn"], cfg,
                            _apply_ln(lp, "ln2", xc, cfg.norm_eps), lck, lcv)

@@ -72,9 +72,11 @@ def _shared_fwd(p, cfg, x, x0, *, pos_offset=0, chunk=512):
     return x + swiglu(p["mlp"], h), kv
 
 
-def _shared_decode(p, cfg, x, x0, ck, cv, slot_pos, pos):
+def _shared_decode(p, cfg, x, x0, ck, cv, slot_pos, pos, slot):
     h = rmsnorm(jnp.concatenate([x, x0], axis=-1), p["ln1"], cfg.norm_eps)
-    a, ck, cv, _ = A.gqa_decode(p["attn"], cfg, h, ck, cv, slot_pos, pos)
+    a, k, v = A.gqa_decode(p["attn"], cfg, h, ck, cv, slot_pos, pos, slot)
+    ck = jax.lax.dynamic_update_slice(ck, k, (0, slot, 0, 0))
+    cv = jax.lax.dynamic_update_slice(cv, v, (0, slot, 0, 0))
     x = x + a
     h = rmsnorm(jnp.concatenate([x, x0], axis=-1), p["ln2"], cfg.norm_eps)
     return x + swiglu(p["mlp"], h), ck, cv
@@ -149,8 +151,9 @@ def hybrid_decode_step(params, cfg, cache, tokens):
     x = embed_tokens(params["embed"], tokens)
     x0 = x
     cache = dict(cache)
+    slot = A.cache_slot(cfg, cache["slot_pos"].shape[0], pos)
     slot_pos = jax.lax.dynamic_update_slice(
-        cache["slot_pos"], pos[None].astype(jnp.int32), (pos,))
+        cache["slot_pos"], pos[None].astype(jnp.int32), (slot,))
     cache["slot_pos"] = slot_pos
 
     def mamba_body(xc, lin):
@@ -163,7 +166,7 @@ def hybrid_decode_step(params, cfg, cache, tokens):
         glp, gssm, gconv, gk, gv = gin
         xc, (ssm, conv) = jax.lax.scan(mamba_body, xc, (glp, gssm, gconv))
         xc, ck, cv = _shared_decode(params["shared"], cfg, xc, x0, gk, gv,
-                                    slot_pos, pos)
+                                    slot_pos, pos, slot)
         return xc, (ssm, conv, ck, cv)
 
     x, (ssm, conv, k, v) = jax.lax.scan(

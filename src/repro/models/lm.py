@@ -15,6 +15,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.packing import is_packed
 from repro.models import attention as A
 from repro.models import mamba2 as M
 from repro.models import moe as MOE
@@ -74,9 +75,12 @@ def _layer_fwd(p, cfg, x, kind: str, *, pos_offset=0, chunk=512,
     return x + h, kv, aux
 
 
-def _layer_decode(p, cfg, x, lcache, slot_pos, pos, kind: str,
+def _layer_decode(p, cfg, x, lcache, slot_pos, pos, slot, kind: str,
                   valid_from=None):
-    """One-token step through one layer.  Returns (x, new_lcache)."""
+    """One-token step through one layer.  Returns (x, new): the new SSM
+    state for ``ssm``, else the token's two cache entries (B,1,...) for
+    the caller to write into ``lcache``'s ``slot``, which the layer
+    masks; ``lcache`` is read only."""
     if kind == "ssm":
         h, ssm, conv = M.mamba2_decode(p["mamba"], cfg,
                                        rmsnorm(x, p["ln1"], cfg.norm_eps),
@@ -84,13 +88,14 @@ def _layer_decode(p, cfg, x, lcache, slot_pos, pos, kind: str,
         return x + h, (ssm, conv)
     hin = rmsnorm(x, p["ln1"], cfg.norm_eps)
     if cfg.use_mla:
-        h, c, kr = A.mla_decode(p["attn"], cfg, hin, lcache[0], lcache[1], pos,
+        h, c, kr = A.mla_decode(p["attn"], cfg, hin, lcache[0], lcache[1],
+                                pos, slot,
                                 valid_from=valid_from)
         new = (c, kr)
     else:
-        h, ck, cv, _ = A.gqa_decode(p["attn"], cfg, hin, lcache[0], lcache[1],
-                                    slot_pos, pos, valid_from=valid_from)
-        new = (ck, cv)
+        h, k, v = A.gqa_decode(p["attn"], cfg, hin, lcache[0], lcache[1],
+                               slot_pos, pos, slot, valid_from=valid_from)
+        new = (k, v)
     x = x + h
     hin = rmsnorm(x, p["ln2"], cfg.norm_eps)
     if kind == "moe":
@@ -122,10 +127,13 @@ def layer_stack(cfg, x, layer_params, step, extras=(), *, remat=None,
     ProgramStore can fingerprint (DESIGN.md §13).
 
     ``step(lp, x, *extra_slices) -> (x, per_layer_out)`` is the per-layer
-    body; ``extras`` are layer-stacked carries scanned alongside the params
-    (e.g. per-layer cache slabs).  ``remat``/``scan`` default to the config
-    flags (forward); decode passes ``remat=False, scan=True`` explicitly —
-    a one-token step never recomputes and always scans.
+    body; ``extras`` are layer-stacked arrays scanned alongside the params
+    (SSM state, which each decode step rewrites whole).  Decode passes
+    the layer index as ``layer_params`` and reads the params and its
+    cache in place (:func:`lm_decode_step`).  ``remat``/``scan`` default
+    to the config flags (forward);
+    decode passes ``remat=False, scan=True`` explicitly — a one-token
+    step never recomputes and always scans.
     """
     remat = cfg.remat if remat is None else remat
     scan = cfg.scan_layers if scan is None else scan
@@ -318,41 +326,68 @@ def lm_prefill(params, cfg, batch, cache, *, chunk: int = 512):
     return logits[:, -1:], cache
 
 
+def _layer_view(layers, i):
+    """Layer ``i`` of the stacked layer params, read in place: a packed
+    weight becomes the view its kernel indexes (``PackedTensor.at_layer``);
+    any other leaf is indexed here."""
+    return jax.tree.map(lambda v: v.at_layer(i) if is_packed(v) else v[i],
+                        layers, is_leaf=is_packed)
+
+
 def lm_decode_step(params, cfg, cache, tokens):
-    """tokens (B,1) -> (logits (B,1,V) f32, updated cache)."""
+    """tokens (B,1) -> (logits (B,1,V) f32, updated cache).
+
+    The layer scan walks the layer index alone.  The stacked params and a
+    positional cache (K/V, or MLA's c/kr) are loop invariants read in
+    place: a packed weight by its kernel at row ``i``, the cache slab as
+    ``cache[i]``.  The scan returns only the token's cache entries
+    (L,B,1,...); one write per cache tensor lands after it.  A scan
+    output cannot alias its input, so a cache passed as ``xs``/``ys``
+    would be sliced, restacked and copied whole on every step, and a
+    weight passed as ``xs`` is sliced out ahead of its kernel.  SSM state
+    is rewritten whole each step and stays scanned."""
     kind = _kind(cfg)
     pos = cache["pos"]
     x = embed_tokens(params["embed"], tokens)
     cache = dict(cache)
+    layers = params["layers"]
+    idx = jnp.arange(jax.tree.leaves(layers)[0].shape[0])
 
-    if kind != "ssm":
-        slots = cache["slot_pos"].shape[0]
-        slot = pos % slots if cfg.sliding_window else pos
-        with jax.named_scope("cache_write"):
-            slot_pos = jax.lax.dynamic_update_slice(
-                cache["slot_pos"], pos[None].astype(jnp.int32), (slot,))
-        cache["slot_pos"] = slot_pos
-        valid_from = cache.get("valid_from")
-        for i in range(cfg.first_k_dense):
-            a, b_ = _cache_pair_names(cfg)
-            lc = (cache[f"dense{i}_{a}"], cache[f"dense{i}_{b_}"])
-            x, new = _layer_decode(params[f"dense{i}"], cfg, x, lc, slot_pos,
-                                   pos, "dense", valid_from=valid_from)
-            cache[f"dense{i}_{a}"], cache[f"dense{i}_{b_}"] = new
-        a, b_ = _cache_pair_names(cfg)
-        extras = (cache[a], cache[b_])
+    if kind == "ssm":
+        def step(i, xc, ssm, conv):
+            return _layer_decode(_layer_view(layers, i), cfg, xc,
+                                 (ssm, conv), None, pos, None, kind)
+
+        x, (cache["ssm"], cache["conv"]) = layer_stack(
+            cfg, x, idx, step, (cache["ssm"], cache["conv"]),
+            remat=False, scan=True)
     else:
-        a, b_ = "ssm", "conv"
-        slot_pos = valid_from = None
-        extras = (cache["ssm"], cache["conv"])
+        slot_pos, valid_from = cache["slot_pos"], cache.get("valid_from")
+        slot = A.cache_slot(cfg, slot_pos.shape[0], pos)
+        a, b_ = _cache_pair_names(cfg)
+        new = {}
+        for i in range(cfg.first_k_dense):
+            da, db = f"dense{i}_{a}", f"dense{i}_{b_}"
+            x, (new[da], new[db]) = _layer_decode(
+                params[f"dense{i}"], cfg, x, (cache[da], cache[db]), slot_pos,
+                pos, slot, "dense", valid_from=valid_from)
+        ca, cb = cache[a], cache[b_]
 
-    def step(lp, xc, c0, c1):
-        return _layer_decode(lp, cfg, xc, (c0, c1), slot_pos, pos, kind,
-                             valid_from=valid_from)
+        def step(i, xc):
+            return _layer_decode(_layer_view(layers, i), cfg, xc,
+                                 (ca[i], cb[i]), slot_pos, pos, slot, kind,
+                                 valid_from=valid_from)
 
-    x, (n0, n1) = layer_stack(cfg, x, params["layers"], step, extras,
-                              remat=False, scan=True)
-    cache[a], cache[b_] = n0, n1
+        x, (new[a], new[b_]) = layer_stack(cfg, x, idx, step, remat=False,
+                                           scan=True)
+        with jax.named_scope("cache_write"):
+            for name, entry in new.items():
+                lead = (0, 0) if name in (a, b_) else (0,)   # (layer,) batch
+                start = lead + (slot,) + (0,) * (entry.ndim - len(lead) - 1)
+                cache[name] = jax.lax.dynamic_update_slice(
+                    cache[name], entry.astype(cache[name].dtype), start)
+            cache["slot_pos"] = jax.lax.dynamic_update_slice(
+                slot_pos, pos[None].astype(jnp.int32), (slot,))
 
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params["embed"], x, cfg.tie_embeddings)

@@ -93,6 +93,31 @@ def test_sharded_queue_parity_token_for_token():
     assert "OK sharded queue parity" in out
 
 
+def test_sharded_kernels_read_layer_of_stacked_weights():
+    """Under a mesh each planned kernel runs per shard (``shard_map``)
+    and reads its layer of the stacked packed weight by the scan's layer
+    index: the sharded engine's Pallas decode reproduces the
+    single-device one token for token."""
+    out = run_sub("""
+        os.environ["REPRO_TSMM_IMPL"] = "pallas_interpret"
+        cfg = get_reduced_config("qwen1_5_4b").reduced(
+            d_model=512, d_ff=1024, num_layers=2, vocab_size=1024,
+            num_heads=8, num_kv_heads=8, head_dim=64, dtype="float32")
+        params, axes = build_model(cfg).init(jax.random.PRNGKey(0))
+        kw = dict(max_len=32, buckets=(2,), max_prompt=8)
+        sh = Engine(build_model(cfg), params, axes, mesh=mesh, opts=opts,
+                    **kw)
+        ref = Engine(build_model(cfg), params, axes, **kw)
+        assert any(k.startswith("layers") for k in sh.pack_report)
+        batch = {"tokens": np.asarray(
+            np.random.default_rng(0).integers(0, 1024, (2, 8)), np.int32)}
+        res, res0 = sh.generate(batch, steps=3), ref.generate(batch, steps=3)
+        assert np.array_equal(np.asarray(res.tokens), np.asarray(res0.tokens))
+        print("OK sharded layer-view kernels")
+    """)
+    assert "OK sharded layer-view kernels" in out
+
+
 def test_sharded_decode_collective_contract():
     """The CI contract: per decode step the stored TP program performs
     EXACTLY 3 all-reduces (attention out / MLP down projections, XLA-

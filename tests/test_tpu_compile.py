@@ -132,3 +132,38 @@ def test_planned_kernel_named_after_its_leaf(one_chip, monkeypatch):
     assert calls and all(re.search(r"%tsmm_w_down(\.\d+)? = ", line)
                          for line in calls)
     assert all("/w_down/" in line for line in calls)
+
+
+@pytest.mark.parametrize("spec", [None, "ksplit:splits=2"])
+def test_layer_view_kernel_reads_stacked_weight(one_chip, monkeypatch, spec):
+    """A layer view's kernel (``PackedTensor.at_layer`` in a scan over the
+    layer index) takes the whole layer-stacked weight: the program holds
+    no slice of one layer's blocks ahead of the kernel."""
+    from repro.core.linear import linear
+    from repro.core.packing import pack
+
+    monkeypatch.setenv("REPRO_TSMM_IMPL", "pallas")
+    if spec is not None:
+        monkeypatch.setenv("REPRO_TSMM_VARIANT", spec)
+    plan = _best(Problem(16, D_MODEL, D_FF))
+    w = jax.ShapeDtypeStruct((3, D_MODEL, D_FF), jnp.bfloat16)
+    packed = jax.eval_shape(lambda w: pack(w, plan.bk, plan.bn), w)
+    packed = jax.tree.map(
+        lambda s: _struct(s.shape, s.dtype, one_chip), packed)
+    x = _struct((16, D_MODEL), jnp.bfloat16, one_chip)
+
+    def f(x, w):
+        def body(xc, i):
+            h = linear(xc, w.at_layer(i), name="w_gate")
+            return xc + h[:, :D_MODEL], None
+        return jax.lax.scan(body, x, jnp.arange(3))[0]
+
+    hlo = jax.jit(f).lower(x, packed).compile().as_text()
+    stacked = "bf16[" + ",".join(map(str, packed.blocks.shape)) + "]"
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls and all(stacked in line.split("operand_layout")[-1]
+                         for line in calls)
+    one = "bf16[" + ",".join(map(str, packed.blocks.shape[1:])) + "]"
+    assert not any(re.search(rf"= {re.escape(one)}\S* (fusion|dynamic-slice)\(",
+                             line) for line in hlo.splitlines())

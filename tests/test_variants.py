@@ -164,6 +164,25 @@ def test_skinny_variant_parity_interpret(spec, m, k, n, dtype):
                                np.asarray(want, np.float32), **_tol(dtype))
 
 
+@pytest.mark.parametrize("impl", ["pallas_interpret", "xla"])
+@pytest.mark.parametrize("spec", sampled_specs_for("skinny_a", prepack=False),
+                         ids=lambda s: s.key())
+def test_skinny_variant_reads_layer_of_stacked_weight(spec, impl):
+    """With ``layer``, every variant reads that row of a layer-stacked
+    packed weight itself and gives what it gives on the row's slice."""
+    m, k, n = SKINNY_SHAPES[0]
+    x, bias = _mk((m, k), jnp.float32), _mk((n,), jnp.float32)
+    stacked = jnp.stack([ops.pack_blocks(_mk((k, n), jnp.float32), 128, 128)
+                         for _ in range(3)])
+    for layer in range(3):
+        got = run_skinny_a(spec, x, stacked, bias, "gelu", bk=128, bn=128,
+                           packed=True, impl=impl,
+                           layer=jnp.asarray(layer, jnp.int32))
+        want = run_skinny_a(spec, x, stacked[layer], bias, "gelu", bk=128,
+                            bn=128, packed=True, impl=impl)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_verify_variants_all_ok():
     rows = verify_variants(impl="xla")
     assert rows and all(r["ok"] for r in rows), rows
