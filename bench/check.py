@@ -27,12 +27,23 @@ ROW_BLOCK = 256
 
 
 def reference_class(name: str):
-    """The reference named by a configuration (``bench/configs/<name>.py``)."""
+    """The ``Reference`` of the module a configuration names
+    (``bench/configs/<name>.py``), a class with this interface:
+
+    - ``Reference(model, seed, *, quant="")``: the plain float32 model of
+      the configuration's ``model`` block, its weights regenerated from
+      ``seed`` by ``bench.weights``; ``quant`` names the lower-precision
+      control (``""`` is the reference itself);
+    - ``hidden(tokens)``: (B, S) token ids to (B, S, d) hidden states
+      after the last layer;
+    - ``logits(hrows)``: (R, d) hidden rows to (R, vocab) float32 logits,
+      the final norm and the output head applied.
+    """
     path = CONFIG_DIR / f"{name}.py"
     spec = importlib.util.spec_from_file_location(f"bench_ref_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.DenseLM
+    return mod.Reference
 
 
 def draw_sample(records, k: int, seed: int) -> list:

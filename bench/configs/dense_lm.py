@@ -140,3 +140,6 @@ class DenseLM:
     def logits(self, hrows):
         """(R, d) hidden rows -> (R, vocab) float32 logits."""
         return self._logits(hrows)
+
+
+Reference = DenseLM

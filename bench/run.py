@@ -51,6 +51,7 @@ from bench import traffic  # noqa: E402
 STATE = ROOT / ".cache" / "bench"
 METRICS_DIR = ROOT / "bench" / "metrics"
 CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
+KERNEL_NAME = re.compile(r"^\s*(?:ROOT\s+)?%tsmm_(\w+?)(?:\.\d+)* = ")
 DRAIN_S = 60.0             # an open loop's wait for its last requests
 CLOSED_REQUESTS = 4096     # a closed loop's queue, more than a window takes
 TRACE_AT = 0.3             # the traced part of the window: its start...
@@ -126,17 +127,57 @@ class Compiles:
         monitoring.register_event_duration_secs_listener(on_duration)
 
 
-def kernel_widths(hlo: str) -> tuple:
-    """(number of tpu_custom_call ops, set of their output widths)."""
-    widths = set()
+def kernel_leaves(hlo: str) -> tuple:
+    """(number of tpu_custom_call ops, set of the leaf names of those the
+    program named ``tsmm_<leaf>``): an instruction keeps its kernel's
+    name, with XLA's ``.<n>`` suffix."""
+    leaves = set()
     n = 0
     for line in hlo.splitlines():
         if CUSTOM_CALL in line and " = " in line:
             n += 1
-            m = re.search(r"=\s*\(?\s*\w+\[([\d,]*)\]", line)
-            if m and m.group(1):
-                widths.add(int(m.group(1).split(",")[-1]))
-    return n, widths
+            m = KERNEL_NAME.match(line)
+            if m:
+                leaves.add(m.group(1))
+    return n, leaves
+
+
+def param_leaves(model) -> tuple:
+    """({path: (shape, dtype, stacked)}, logical axes) of the params of
+    ``model`` (``repro.models.registry.build_model``), from shapes alone;
+    a stacked leaf (under ``layers``) has a leading layer dim."""
+    import jax
+    captured = {}
+
+    def init_shapes(rng):
+        p, a = model.init(rng)
+        captured["axes"] = a
+        return p
+
+    shapes = jax.eval_shape(init_shapes, jax.random.PRNGKey(0))
+    leaves = {}
+
+    def walk(t, path):
+        if isinstance(t, dict):
+            for k in t:
+                walk(t[k], path + (k,))
+        else:
+            leaves["/".join(path)] = (tuple(t.shape), t.dtype,
+                                      path[0] == "layers")
+
+    walk(shapes, ())
+    return leaves, captured["axes"]
+
+
+def seeded_params(leaves: dict, seed: int) -> dict:
+    """The nested params of ``leaves`` from ``seed`` (``bench/weights.py``),
+    made on the default device in one jitted call."""
+    import jax
+    import jax.numpy as jnp
+    from bench import weights as W
+    words = jnp.asarray(W.seed_words(seed))
+    flat = jax.jit(lambda w: W.make_tree(w, leaves))(words)
+    return nest(jax.block_until_ready(flat))
 
 
 def nest(flat: dict) -> dict:
@@ -171,10 +212,6 @@ class Bench:
     # -- set-up ------------------------------------------------------------
 
     def setup(self) -> None:
-        import jax
-        import jax.numpy as jnp
-
-        from bench import weights as W
         from repro.configs.base import ModelConfig
         from repro.core import install, registry
         from repro.core.plan import buckets_for, length_buckets_for
@@ -197,32 +234,11 @@ class Bench:
             self.misses0 = registry.stats()["misses"]
 
         with self.phase("weights"):
-            captured = {}
-
-            def init_shapes(rng):
-                p, a = self.model.init(rng)
-                captured["axes"] = a
-                return p
-
-            shapes = jax.eval_shape(init_shapes, jax.random.PRNGKey(0))
-            leaves = {}
-
-            def walk(t, path):
-                if isinstance(t, dict):
-                    for k in t:
-                        walk(t[k], path + (k,))
-                else:
-                    leaves["/".join(path)] = (tuple(t.shape), t.dtype,
-                                              path[0] == "layers")
-
-            walk(shapes, ())
-            words = jnp.asarray(W.seed_words(self.seed))
-            flat = jax.jit(lambda w: W.make_tree(w, leaves))(words)
-            params = nest(jax.block_until_ready(flat))
-            del flat
+            leaves, axes = param_leaves(self.model)
+            params = seeded_params(leaves, self.seed)
 
         with self.phase("engine"):
-            self.eng = Engine(self.model, params, captured["axes"],
+            self.eng = Engine(self.model, params, axes,
                               max_len=e["max_len"], max_batch=e["slots"],
                               max_prompt=e["max_prompt"],
                               donate_params=True)
@@ -262,8 +278,8 @@ class Bench:
             raise Failure("a warm-up request did not complete")
 
     def _programs(self) -> None:
-        """The module name of the stored programs, the output widths of
-        each program's kernels, and the planned-kernel guard."""
+        """The module name of the stored programs, the leaf names of each
+        program's kernels, and the planned-kernel guard."""
         import jax
         self.kernels, self.module_names = {}, set()
         # off the chip the kernels run as XLA or in interpret mode
@@ -275,11 +291,11 @@ class Bench:
                     else f"{prog.kind}.{tokens}")
             hlo = prog.executable.as_text()
             self.module_names.add(hlo.split(",", 1)[0].split()[-1])
-            n, widths = kernel_widths(hlo)
+            n, leaves = kernel_leaves(hlo)
             if n < need:
                 raise Failure(f"{name}: {n} tpu_custom_call < {need} "
                               f"packed weights")
-            self.kernels[name] = widths
+            self.kernels[name] = leaves
 
     # -- window ------------------------------------------------------------
 
@@ -441,7 +457,7 @@ class Bench:
         for name, row in sorted(red.by_kind().items()):
             print(f"trace: {name} x{row['n']}: {row['ns'] * 1e-6:.3f} ms "
                   f"device, {row['custom_ns'] * 1e-6:.3f} ms in "
-                  f"tpu_custom_call; kernel widths "
+                  f"tpu_custom_call; kernel leaves "
                   f"{sorted(self.kernels.get(name, ()))}", file=sys.stderr)
         window_ns = red.window[1] - red.window[0]
         device["busy_s"] = red.busy_ns * 1e-9
@@ -522,7 +538,7 @@ class MetricContext:
     reduced: object            # bench.trace.Reduced
     records: list              # bench.serve_loop.Record
     trace_window: tuple        # host perf_counter (start, end)
-    kernels: dict              # program name -> kernel output widths
+    kernels: dict              # program name -> kernel leaf names
     slots: int
     mix: dict
     counters: tuple            # scheduler counters at window open, close

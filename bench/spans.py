@@ -38,7 +38,8 @@ LAYERS = "layers"
 # scopes the serving programs open, whose ops are attributed to them
 SCOPES = frozenset({
     "embed", "layer", "norm", "rope", "attention", "cache_write", "mlp",
-    "head", "wq", "wk", "wv", "wqkv", "wo", "w_gate", "w_up", "w_down"})
+    "head", "wq", "wk", "wv", "wqkv", "wo", "w_gate", "w_up", "w_down",
+    "wq_a", "wq_b", "wkv_a", "wkv_b", "ws_gate", "ws_up", "ws_down"})
 _MODULE = re.compile(r"^(?:jit_)?(?P<name>.*?)(?:\(\d+\))?$")
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%(?P<name>[\w.\-]+) = .*?"
                     r"metadata=\{op_name=\"(?P<op>[^\"]*)\"")

@@ -76,6 +76,22 @@ def test_weights_of_one_layer_match_the_stacked_tree():
     assert abs(std - 1 / 8) < 0.01
 
 
+@pytest.mark.parametrize("config", sorted(p.stem for p in
+                                    CONFIGS.glob("*.json")))
+def test_reference_class_is_the_named_module_reference(config):
+    name = json.loads((CONFIGS / f"{config}.json").read_text())["reference"]
+    lm = check.reference_class(name)(SMALL, 11)
+    h = lm.hidden(np.zeros((1, 4), np.int32))
+    assert h.shape == (1, 4, SMALL["d_model"])
+    assert lm.logits(h[0]).shape == (4, SMALL["vocab_size"])
+
+
+def test_dense_reference_is_the_dense_lm():
+    from bench.configs import dense_lm
+    assert dense_lm.Reference is dense_lm.DenseLM
+    assert check.reference_class("dense_lm").__name__ == "DenseLM"
+
+
 def test_reference_ranks_its_own_choice_first():
     rng = np.random.default_rng(5)
     seqs = [(rng.integers(0, 2048, 20).astype(np.int32),

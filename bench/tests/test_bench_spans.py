@@ -25,6 +25,11 @@ LEAVES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "head")
 @pytest.mark.parametrize("op_name, label", [
     ("jit(decode_step_b4)/layers/while/body/closed_call/layer/mlp/w_gate/"
      "jit(_skinny_compute)/tsmm_w_gate/pallas_call", "w_gate"),
+    # latent attention's and the shared experts' leaves are scopes too
+    ("jit(decode_step_b4)/layers/while/body/closed_call/layer/wkv_a/"
+     "jit(_skinny_compute)/tsmm_wkv_a/pallas_call", "wkv_a"),
+    ("jit(decode_step_b4)/layers/while/body/closed_call/layer/ws_down/"
+     "dot_general", "ws_down"),
     ("jit(decode_step_b4)/layers/while/body/closed_call/layer/attention/"
      "bhgd,bkhd->bhgk/dot_general", "attention"),
     ("jit(decode_step_b4)/layers/while/body/dynamic_slice", spans.SCAN),

@@ -31,6 +31,7 @@ the chip the cell names.
 from __future__ import annotations
 
 import argparse
+import collections
 import glob
 import json
 import shutil
@@ -67,15 +68,13 @@ def split_table(st, prefix: str) -> None:
 
 def leaf_least_s(model: dict, m: int, peak: dict) -> dict:
     """Least time (``bench/counts.py``) of one program execution's
-    matmuls of each leaf over ``m`` rows: every layer's, and the head."""
-    out = {}
-    for name, k, n, b in counts.layer_linears(model):
-        t, _ = counts.least_time([counts.Matmul(name, m, k, n, b)], peak)
-        out[name] = t * model["num_layers"]
-    out["head"] = counts.least_time(
-        [counts.Matmul("head", m, model["d_model"], model["vocab_size"])],
-        peak)[0]
-    return out
+    matmuls of each leaf over ``m`` rows: every layer's, and the head;
+    routed experts left out, since no kernel runs them."""
+    out = collections.Counter()
+    for c in counts.step_matmuls(model, m):
+        if not c.routed:
+            out[c.leaf] += counts.least_time([c], peak)[0]
+    return dict(out)
 
 
 def leaf_table(st, prefix: str, model: dict, m: int, peak: dict) -> None:

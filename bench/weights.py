@@ -8,9 +8,14 @@ program's whole tree comes out of one jitted call on the device, and the
 reference regenerates any single layer on its own, bit for bit, without
 taking anything that the program has made.
 
-Scales follow the usual init of a decoder LM: a dense weight of fan-in
-``k`` has std ``1/sqrt(k)``, the token table 0.02, a QKV bias 0.1 (so
-that the bias epilogue is exercised), and every norm scale is 1.
+Scales follow the init the models use (``repro.models.param``, with
+the experts' ``fan_in``): a weight has std ``1/sqrt(k)`` for its
+contraction axis ``k`` (the second-to-last, so that an ``(E, k, n)``
+expert stack is scaled by its own fan-in and not by its expert count),
+the token table 0.02, a QKV bias 0.1 (so that the bias epilogue is
+exercised), and every norm scale (``ln1``, ``ln2`` and any leaf whose
+name ends in ``norm``: the final norm, MLA's ``q_norm``/``kv_norm``,
+mamba2's gated norm) is 1.
 """
 
 from __future__ import annotations
@@ -22,23 +27,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-NORMS = ("ln1", "ln2", "final_norm")
 BIASES = ("bq", "bk", "bv")
 EMBED_STD = 0.02
 BIAS_STD = 0.1
 
 
 def leaf_std(path: str, shape: tuple) -> float:
-    """Standard deviation of a leaf by the last name of its path (0 for
-    a norm scale, which is all ones)."""
+    """Standard deviation of a leaf by the last name of its path and its
+    shape without the layer dim (0 for a norm scale, which is all ones).
+
+    A leaf of rank 1 that is no norm or Q/K/V bias has ``1/sqrt(shape[0])``.
+    The state-space leaves (``a_log``, ``dt_bias``, ``d_skip``, ``conv_w``,
+    ``conv_b``) take these generic rules, not their model's init, until
+    the first SSM configuration needs them."""
     name = path.rsplit("/", 1)[-1]
-    if name in NORMS:
+    if name in ("ln1", "ln2") or name.endswith("norm"):
         return 0.0
     if name == "tok":
         return EMBED_STD
     if name in BIASES:
         return BIAS_STD
-    return 1.0 / math.sqrt(shape[0])
+    return 1.0 / math.sqrt(shape[-2] if len(shape) >= 2 else shape[0])
 
 
 def _mix(x):
