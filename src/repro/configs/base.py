@@ -35,9 +35,12 @@ class ModelConfig:
     head_dim: int = 0                # 0 -> d_model // num_heads
 
     # --- MoE ---------------------------------------------------------------
-    num_experts: int = 0             # routed experts (0 = dense MLP)
+    num_experts: int = 0             # routed experts held (0 = dense MLP)
+    num_experts_total: int = 0       # the router's width (0 -> num_experts)
+    first_expert: int = 0            # index of the first held expert
     num_shared_experts: int = 0
     experts_per_token: int = 0       # top-k
+    norm_topk_prob: bool = True      # renormalise the top-k weights
     d_ff_expert: int = 0             # expert hidden size (d_ff used if 0)
     first_k_dense: int = 0           # leading dense layers (deepseek-v2 style)
     router_aux_coef: float = 0.01
@@ -66,6 +69,14 @@ class ModelConfig:
     sliding_window: int = 0          # 0 = full attention
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    # YaRN rope scaling (``rope_scaling`` of DeepSeek-V2; yarn_factor 0 =
+    # none): MLA's decoupled rope and its softmax scale
+    yarn_factor: float = 0.0
+    yarn_original_max_position_embeddings: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- encoder-decoder (whisper) -------------------------------------------
     is_encoder_decoder: bool = False
@@ -93,6 +104,13 @@ class ModelConfig:
             object.__setattr__(self, "v_head_dim", self.head_dim)
         if self.num_experts and self.d_ff_expert == 0:
             object.__setattr__(self, "d_ff_expert", self.d_ff)
+        if self.num_experts_total == 0:
+            object.__setattr__(self, "num_experts_total", self.num_experts)
+        if self.first_expert + self.num_experts > self.num_experts_total:
+            raise ValueError(
+                f"{self.name}: experts {self.first_expert}.."
+                f"{self.first_expert + self.num_experts - 1} held of "
+                f"{self.num_experts_total}")
 
     # ------------------------------------------------------------------
     @property
@@ -126,7 +144,12 @@ class ModelConfig:
             vocab_size=512,
         )
         if self.num_experts:
-            small.update(num_experts=8, experts_per_token=min(self.experts_per_token, 2),
+            # a held share stays a share (4 of 16), a whole layer whole
+            held = self.num_experts < self.num_experts_total
+            small.update(num_experts=4 if held else 8,
+                         num_experts_total=16 if held else 8,
+                         first_expert=0,
+                         experts_per_token=min(self.experts_per_token, 2),
                          d_ff_expert=64,
                          num_shared_experts=min(self.num_shared_experts, 1),
                          first_k_dense=min(self.first_k_dense, 1),
@@ -134,8 +157,9 @@ class ModelConfig:
                          # exact prefill/decode parity
                          capacity_factor=8.0)
         if self.use_mla:
-            small.update(kv_lora_rank=32, q_lora_rank=48, rope_head_dim=16,
-                         v_head_dim=32)
+            small.update(kv_lora_rank=32,
+                         q_lora_rank=48 if self.q_lora_rank else 0,
+                         rope_head_dim=16, v_head_dim=32)
         if self.ssm_state:
             small.update(ssm_state=16, ssm_head_dim=16, ssm_chunk=32)
         if self.attn_every:

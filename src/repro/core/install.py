@@ -55,21 +55,29 @@ SERVE_LENGTHS = length_buckets_for(MAX_SERVE_PROMPT)
 
 
 def serving_shapes(cfg) -> set:
-    """The (k, n) weight shapes the decode path hits for one arch."""
+    """The (k, n) weight shapes that ``core.linear`` sees on the serving
+    path of one arch: attention's projections (latent attention's with
+    ``use_mla``), the dense MLP (in a MoE model only its ``first_k_dense``
+    layers), the shared experts, the SSM projections and the head.  The
+    routed experts and the router are einsums, not ``linear`` calls."""
     d, h, kh, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     shapes = set()
-    if h:
+    if cfg.use_mla:
+        dr, dv, qr, kvr = (cfg.rope_head_dim, cfg.v_head_dim,
+                           cfg.q_lora_rank, cfg.kv_lora_rank)
+        shapes |= ({(d, qr), (qr, h * (hd + dr))} if qr
+                   else {(d, h * (hd + dr))})
+        shapes |= {(d, kvr + dr), (kvr, h * (hd + dv)), (h * dv, d)}
+    elif h:
         shapes |= {(d, h * hd), (d, kh * hd), (h * hd, d)}
-    if cfg.d_ff:
+    if cfg.d_ff and (not cfg.num_experts or cfg.first_k_dense):
         shapes |= {(d, cfg.d_ff), (cfg.d_ff, d)}
-    if cfg.num_experts:
-        shapes |= {(d, cfg.d_ff_expert), (cfg.d_ff_expert, d)}
+    if cfg.num_shared_experts:
+        sff = cfg.num_shared_experts * cfg.d_ff_expert
+        shapes |= {(d, sff), (sff, d)}
     if cfg.ssm_state:
         di, g, n = cfg.d_inner, cfg.ssm_groups, cfg.ssm_state
         shapes |= {(d, 2 * di + 2 * g * n + cfg.ssm_heads), (di, d)}
-    if cfg.use_mla:
-        shapes |= {(d, cfg.q_lora_rank), (cfg.kv_lora_rank,
-                                          h * (cfg.head_dim + cfg.v_head_dim))}
     shapes.add((d, cfg.vocab_size))
     return shapes
 

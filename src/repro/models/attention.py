@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.linear import in_serving_ctx, linear
-from repro.models.layers import apply_rope, rope_tables
+from repro.models.layers import (apply_rope, rmsnorm, rope_tables,
+                                 yarn_mscale, yarn_of)
 from repro.models.param import ParamTree
 from repro.sharding.context import get_ctx, shard_act
 
@@ -89,7 +90,7 @@ def _flash_per_shard(q, k, v, causal: bool):
 @jax.named_scope("attention")
 def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
                       chunk: int = 512, q_offset=0, k_offset=None,
-                      valid_from=None):
+                      valid_from=None, scale=None):
     """q: (B,Sq,H,D)  k,v: (B,Sk,KH,D).  Returns (B,Sq,H,D).
 
     Online-softmax double scan: outer over q chunks (sequential, O(1)
@@ -100,7 +101,8 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
     ragged admission at a nonzero clock — keeps a correct causal mask);
     pass ``k_offset=0`` for cross-attention keys that start at 0.
     ``valid_from``: (B,) absolute first-real-token position per row
-    (left-pad masking); ``q_offset`` may be traced under jit.
+    (left-pad masking); ``q_offset`` may be traced under jit.  ``scale``
+    multiplies the scores (default ``D ** -0.5``).
 
     On TPU, full-window self-attention traced for serving
     (``core.linear.serving_ctx``) dispatches to the fused Pallas flash
@@ -112,6 +114,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
     if k_offset is None:
         k_offset = q_offset
     if (in_serving_ctx() and jax.default_backend() == "tpu" and window == 0
+            and scale is None and v.shape[-1] == q.shape[-1]
             and isinstance(q_offset, int) and q_offset == 0
             and isinstance(k_offset, int) and k_offset == 0
             and valid_from is None
@@ -123,7 +126,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, window: int = 0,
     sk, kh = k.shape[1], k.shape[2]
     dv = v.shape[-1]          # may differ from d (MLA: dk=nope+rope, dv=v)
     g = h // kh
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
     cq = _divisor_chunk(sq, chunk)
     ck = _divisor_chunk(sk, chunk)
     nq, nk = sq // cq, sk // ck
@@ -316,9 +319,12 @@ def init_mla(rng, cfg):
     dn, dr, dv = cfg.head_dim, cfg.rope_head_dim, cfg.v_head_dim
     qr, kvr = cfg.q_lora_rank, cfg.kv_lora_rank
     pt = ParamTree(rng, cfg.dtype)
-    pt.dense("wq_a", (d, qr), ("embed", "lora"))
-    pt.ones("q_norm", (qr,), ("lora",))
-    pt.dense("wq_b", (qr, h * (dn + dr)), ("lora", "qheads"))
+    if qr:
+        pt.dense("wq_a", (d, qr), ("embed", "lora"))
+        pt.ones("q_norm", (qr,), ("lora",))
+        pt.dense("wq_b", (qr, h * (dn + dr)), ("lora", "qheads"))
+    else:
+        pt.dense("wq", (d, h * (dn + dr)), ("embed", "qheads"))
     pt.dense("wkv_a", (d, kvr + dr), ("embed", "lora"))
     pt.ones("kv_norm", (kvr,), ("lora",))
     pt.dense("wkv_b", (kvr, h * (dn + dv)), ("lora", "qheads"))
@@ -326,20 +332,36 @@ def init_mla(rng, cfg):
     return pt.build()
 
 
-def _mla_qkv_train(p, cfg, x, pos):
-    from repro.models.layers import rmsnorm
-    b, s, _ = x.shape
-    h, dn, dr, dv = cfg.num_heads, cfg.head_dim, cfg.rope_head_dim, cfg.v_head_dim
+def mla_scale(cfg) -> float:
+    """MLA's softmax scale: (dn + dr) ** -0.5, times mscale(factor,
+    mscale_all_dim) squared under YaRN (DeepSeek-V2's attention)."""
+    scale = (cfg.head_dim + cfg.rope_head_dim) ** -0.5
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_q(p, cfg, x):
+    """x (..., d) -> q (..., h * (dn + dr)): through the Q LoRA, or one
+    direct projection when ``q_lora_rank`` is 0."""
+    if not cfg.q_lora_rank:
+        return linear(x, p["wq"], name="wq")
     cq = rmsnorm(linear(x, p["wq_a"], name="wq_a"), p["q_norm"],
                  cfg.norm_eps)
-    q = linear(cq, p["wq_b"], name="wq_b").reshape(b, s, h, dn + dr)
+    return linear(cq, p["wq_b"], name="wq_b")
+
+
+def _mla_qkv_train(p, cfg, x, pos):
+    b, s, _ = x.shape
+    h, dn, dr, dv = cfg.num_heads, cfg.head_dim, cfg.rope_head_dim, cfg.v_head_dim
+    q = _mla_q(p, cfg, x).reshape(b, s, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     ckv = linear(x, p["wkv_a"], name="wkv_a")
     c_kv = rmsnorm(ckv[..., : cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
     k_rope = ckv[..., cfg.kv_lora_rank:][:, :, None, :]      # (B,S,1,dr)
     kv = linear(c_kv, p["wkv_b"], name="wkv_b").reshape(b, s, h, dn + dv)
     k_nope, v = kv[..., :dn], kv[..., dn:]
-    cos, sin = rope_tables(pos, dr, cfg.rope_theta)
+    cos, sin = rope_tables(pos, dr, cfg.rope_theta, yarn_of(cfg))
     q_rope = apply_rope(q_rope, cos, sin)
     k_rope = apply_rope(k_rope, cos, sin)
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
@@ -355,8 +377,8 @@ def mla_forward(p, cfg, x, *, pos_offset=0, chunk: int = 512,
     pos = pos_offset + jnp.arange(s)
     q, k, v, c_kv, k_rope = _mla_qkv_train(p, cfg, x, pos)
     out = chunked_attention(q, k, v, causal=True, chunk=chunk,
-                            q_offset=pos_offset, valid_from=valid_from)
-    # note: softmax scale uses full q dim (dn+dr) inside chunked_attention
+                            q_offset=pos_offset, valid_from=valid_from,
+                            scale=mla_scale(cfg))
     out = out.reshape(b, s, cfg.num_heads * cfg.v_head_dim)
     return linear(out, p["wo"], name="wo"), (c_kv, k_rope)
 
@@ -366,22 +388,22 @@ def mla_decode(p, cfg, x, cache_c, cache_kr, cur_pos, slot, *,
     """Absorbed-matrix decode over the compressed cache, read only.
 
     cache_c: (B,S,kvr)  cache_kr: (B,S,dr).  The q_nope->c-space and
-    c->v absorbtions avoid materializing per-head K/V for 32k positions —
-    and both absorbed GEMMs are TSMM-shaped (B x kvr against wide heads).
+    c->v absorbtions avoid materializing per-head K/V for 32k positions;
+    they take ``wkv_b`` as per-head einsum operands, so it stays unpacked
+    (``serve.engine.PACKABLE``).  The scores, softmax and values run
+    under the ``attention`` scope.
 
     Returns ``(out, c, kr)``: the token's compressed entries (B,1,kvr) and
     (B,1,dr) are attended as they are and left for the caller to write
     into ``slot`` (:func:`cache_slot`), whose old occupant is masked.
     """
-    from repro.models.layers import rmsnorm
     b = x.shape[0]
     h, dn, dr, dv, kvr = (cfg.num_heads, cfg.head_dim, cfg.rope_head_dim,
                           cfg.v_head_dim, cfg.kv_lora_rank)
-    cq = rmsnorm(linear(x, p["wq_a"], name="wq_a"), p["q_norm"],
-                 cfg.norm_eps)
-    q = linear(cq, p["wq_b"], name="wq_b").reshape(b, h, dn + dr)
+    q = _mla_q(p, cfg, x).reshape(b, h, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    cos, sin = rope_tables(jnp.asarray([cur_pos]), dr, cfg.rope_theta)
+    cos, sin = rope_tables(jnp.asarray([cur_pos]), dr, cfg.rope_theta,
+                           yarn_of(cfg))
     q_rope = apply_rope(q_rope[:, None], cos, sin)[:, 0]     # (B,h,dr)
 
     ckv = linear(x[:, 0], p["wkv_a"], name="wkv_a")
@@ -389,33 +411,34 @@ def mla_decode(p, cfg, x, cache_c, cache_kr, cur_pos, slot, *,
     kr_new = ckv[..., kvr:]
     kr_new = apply_rope(kr_new[:, None, None], cos, sin)[:, 0, 0]
 
-    wkv_b = p["wkv_b"]
-    w = wkv_b.unpack() if hasattr(wkv_b, "unpack") else wkv_b
-    w = w.reshape(kvr, h, dn + dv)
-    w_uk, w_uv = w[..., :dn], w[..., dn:]
-    q_c = jnp.einsum("bhd,chd->bhc", q_nope, w_uk,
-                     preferred_element_type=jnp.float32)     # absorb into c-space
-    q_rope = q_rope.astype(jnp.float32)
-    scale = (dn + dr) ** -0.5
-    s = (jnp.einsum("bhc,bsc->bhs", q_c, cache_c.astype(jnp.float32))
-         + jnp.einsum("bhr,bsr->bhs", q_rope, cache_kr.astype(jnp.float32)))
-    s = s * scale
-    pos_s = jnp.arange(cache_c.shape[1])
-    valid = (pos_s <= cur_pos) & (pos_s != slot)
-    if valid_from is not None:
-        s = jnp.where((valid[None, :] &
-                       (pos_s[None, :] >= valid_from[:, None]))[:, None],
-                      s, NEG_INF)
-    else:
-        s = jnp.where(valid[None, None], s, NEG_INF)
-    c_f = c_new.astype(jnp.float32)
-    s_new = (jnp.einsum("bhc,bc->bh", q_c, c_f)
-             + jnp.einsum("bhr,br->bh", q_rope, kr_new.astype(jnp.float32)))
-    s = jnp.concatenate([s, (s_new * scale)[..., None]], axis=-1)
-    pattn = jax.nn.softmax(s, axis=-1)
-    o_c = (jnp.einsum("bhs,bsc->bhc", pattn[..., :-1],
-                      cache_c.astype(jnp.float32))
-           + pattn[..., -1:] * c_f[:, None])
-    o = jnp.einsum("bhc,chv->bhv", o_c, w_uv).astype(x.dtype)
+    with jax.named_scope("attention"):
+        w = p["wkv_b"].reshape(kvr, h, dn + dv)
+        w_uk, w_uv = w[..., :dn], w[..., dn:]
+        q_c = jnp.einsum("bhd,chd->bhc", q_nope, w_uk,
+                         preferred_element_type=jnp.float32)  # into c-space
+        q_rope = q_rope.astype(jnp.float32)
+        scale = mla_scale(cfg)
+        s = (jnp.einsum("bhc,bsc->bhs", q_c, cache_c.astype(jnp.float32))
+             + jnp.einsum("bhr,bsr->bhs", q_rope,
+                          cache_kr.astype(jnp.float32)))
+        s = s * scale
+        pos_s = jnp.arange(cache_c.shape[1])
+        valid = (pos_s <= cur_pos) & (pos_s != slot)
+        if valid_from is not None:
+            s = jnp.where((valid[None, :] &
+                           (pos_s[None, :] >= valid_from[:, None]))[:, None],
+                          s, NEG_INF)
+        else:
+            s = jnp.where(valid[None, None], s, NEG_INF)
+        c_f = c_new.astype(jnp.float32)
+        s_new = (jnp.einsum("bhc,bc->bh", q_c, c_f)
+                 + jnp.einsum("bhr,br->bh", q_rope,
+                              kr_new.astype(jnp.float32)))
+        s = jnp.concatenate([s, (s_new * scale)[..., None]], axis=-1)
+        pattn = jax.nn.softmax(s, axis=-1)
+        o_c = (jnp.einsum("bhs,bsc->bhc", pattn[..., :-1],
+                          cache_c.astype(jnp.float32))
+               + pattn[..., -1:] * c_f[:, None])
+        o = jnp.einsum("bhc,chv->bhv", o_c, w_uv).astype(x.dtype)
     out = linear(o.reshape(b, 1, h * dv), p["wo"], name="wo")
     return out, c_new[:, None], kr_new[:, None]

@@ -2,6 +2,9 @@
 
 from __future__ import annotations
 
+import math
+from typing import NamedTuple, Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -36,13 +39,65 @@ def silu(x):
 # ---------------------------------------------------------------------------
 
 
+class Yarn(NamedTuple):
+    """YaRN rope scaling as DeepSeek-V2 publishes it (``rope_scaling``)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float
+    beta_slow: float
+    mscale: float
+    mscale_all_dim: float
+
+
+def yarn_of(cfg) -> Optional[Yarn]:
+    """``cfg``'s YaRN scaling, or None without one."""
+    if not cfg.yarn_factor:
+        return None
+    return Yarn(cfg.yarn_factor, cfg.yarn_original_max_position_embeddings,
+                cfg.yarn_beta_fast, cfg.yarn_beta_slow, cfg.yarn_mscale,
+                cfg.yarn_mscale_all_dim)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_dim(rotations: float, dim: int, theta: float, original: int):
+    """The dim whose wavelength makes ``rotations`` turns over the
+    original context (``yarn_find_correction_dim``)."""
+    return (dim * math.log(original / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
 @jax.named_scope("rope")
-def rope_tables(positions, dim: int, theta: float):
-    """cos/sin tables for given integer positions (any shape)."""
+def rope_tables(positions, dim: int, theta: float,
+                yarn: Optional[Yarn] = None):
+    """cos/sin tables for given integer positions (any shape).
+
+    With ``yarn`` (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``): a
+    linear ramp between the correction dims of ``beta_fast`` and
+    ``beta_slow`` blends each base frequency with itself over
+    ``factor``, and the tables are multiplied by
+    mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
     half = dim // 2
     freqs = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is not None:
+        orig = yarn.original_max_position_embeddings
+        lo = max(math.floor(_yarn_dim(yarn.beta_fast, dim, theta, orig)), 0)
+        hi = min(math.ceil(_yarn_dim(yarn.beta_slow, dim, theta, orig)),
+                 dim - 1)
+        ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - lo)
+                        / ((hi - lo) or 0.001), 0.0, 1.0)
+        freqs = freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., half)
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
+    return cos, sin
 
 
 @jax.named_scope("rope")

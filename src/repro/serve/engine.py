@@ -49,9 +49,11 @@ from repro.sharding.rules import (ShardingOptions, axis_size, packed_pspec,
 log = logging.getLogger(__name__)
 
 # Leaves consumed through core.linear (packable).  MoE expert tensors are
-# consumed by batched einsum and excluded (see DESIGN.md §4).
+# consumed by batched einsum and excluded (see DESIGN.md §4), as is MLA's
+# ``wkv_b``, which the absorbed decode reads as per-head einsum operands.
 PACKABLE = {"wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "w_in",
-            "w_out", "head", "wq_a", "wq_b", "wkv_a", "wkv_b"}
+            "w_out", "head", "wq_a", "wq_b", "wkv_a", "ws_gate", "ws_up",
+            "ws_down"}
 MIN_ROWS, MIN_COLS = 512, 512
 
 
